@@ -7,6 +7,7 @@ from the dry-run artifacts if present.
 """
 from __future__ import annotations
 
+import sys
 import time
 import traceback
 
@@ -24,6 +25,7 @@ def main() -> None:
         ("Live-hot-path-throughput", bench_live_throughput.run),
     ]
     print("name,value,derived")
+    failed = []
     for title, fn in suites:
         t0 = time.time()
         try:
@@ -34,6 +36,7 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc()
             print(f"_meta/{title}_FAILED,{e},")
+            failed.append(title)
 
     # roofline summary (if the dry-run matrix has been generated)
     try:
@@ -43,6 +46,8 @@ def main() -> None:
             print(f"roofline/{dom}_pairs,{len(pairs)},")
     except Exception:
         print("roofline/skipped,0,run `python -m repro.launch.dryrun --all`")
+    if failed:
+        sys.exit(f"benchmark suites failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

@@ -12,7 +12,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import mesh_context
 
 from repro.configs import get_config
 from repro.launch.mesh import make_debug_mesh
@@ -28,7 +27,7 @@ def main():
     mesh = make_debug_mesh(data=2, stage=2, tensor=2)
     batch, steps, cache_len = 8, 24, 64
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: model_lib.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(
                              jax.random.PRNGKey(0))

@@ -15,7 +15,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import mesh_context
 import numpy as np
 
 from repro.configs import TrainConfig, get_config
@@ -54,7 +53,7 @@ def main():
     tc = TrainConfig(learning_rate=3e-4, optimizer="adam",
                      microbatches=2, weight_decay=0.0)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: model_lib.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(
                              jax.random.PRNGKey(0))

@@ -82,7 +82,7 @@ def solve_partition(layer_times, out_sizes, capacities, bandwidths,
     points = [L - 1]
     j, n = L - 1, N
     while n > 1:
-        l = arg[j, n]
+        l = int(arg[j, n])
         points.append(l)
         j, n = l, n - 1
     points = tuple(sorted(points))

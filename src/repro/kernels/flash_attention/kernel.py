@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -75,11 +77,14 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_kernel(q, k, v, q_offset=None, *, causal: bool = True,
                            window: int = 0, scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: [B, H, Sq, dh]; k, v: [B, Hkv, Skv, dh]. Returns [B, H, Sq, dh].
     q_offset: int32 scalar array — global position of q row 0 (chunked
-    prefill against a longer kv cache)."""
+    prefill against a longer kv cache). ``interpret=None`` follows
+    ``kernels.backend.default_interpret``."""
     import jax.numpy as _jnp
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Sq, dh = q.shape
     _, Hkv, Skv, _ = k.shape
     if q_offset is None:

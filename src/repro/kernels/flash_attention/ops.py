@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_reference
 
@@ -28,8 +29,11 @@ def _pad_to(x, axis, mult):
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, window=0, block_q=128, block_k=128,
-                    interpret=True):
-    """q: [B, H, Sq, dh]; k, v: [B, Hkv, Skv, dh]. Returns [B, H, Sq, dh]."""
+                    interpret=None):
+    """q: [B, H, Sq, dh]; k, v: [B, Hkv, Skv, dh]. Returns [B, H, Sq, dh].
+    ``interpret=None`` follows ``kernels.backend.default_interpret``."""
+    if interpret is None:
+        interpret = default_interpret()
     qp, Sq = _pad_to(q, 2, block_q)
     kp, Skv = _pad_to(k, 2, block_k)
     vp, _ = _pad_to(v, 2, block_k)

@@ -22,7 +22,7 @@ def _kernel(p_ref, g_ref, m_ref, po_ref, mo_ref, *, lr, momentum,
 
 def fused_sgd_kernel(params, grads, mom, *, lr: float, momentum: float = 0.9,
                      weight_decay: float = 4e-5, block: int = 65536,
-                     interpret: bool = True):
+                     interpret: bool):
     """params/grads/mom: flat [N] arrays (pad to a block multiple upstream)."""
     (N,) = params.shape
     assert N % block == 0 or N < block, (N, block)

@@ -1,9 +1,9 @@
 """Jit'd wrappers: flat-array update + whole-pytree update (flatten, pad,
 single fused kernel launch, unflatten).
 
-``interpret=None`` (the default) autodetects the backend: the kernel is
-compiled natively on Pallas-capable devices (TPU/GPU) and falls back to
-interpreter mode on CPU, where Pallas has no native lowering. The flatten
+``interpret=None`` (the default) follows ``kernels.backend``: the kernel
+is compiled natively on Pallas-capable devices (TPU/GPU) and interpreted
+on CPU, where Pallas has no native lowering. The flatten
 helpers (``pack_leaves`` / ``unpack_leaves``) are shared with the live
 runtime's packed-buffer layer (``runtime/stage_executor.py``).
 """
@@ -13,20 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.backend import default_interpret
 from repro.kernels.fused_sgd.kernel import fused_sgd_kernel
-
-
-def pallas_native_backend() -> bool:
-    """True when the default JAX backend can compile Pallas natively."""
-    try:
-        return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
-    except Exception:
-        return False
-
-
-def default_interpret() -> bool:
-    """Interpret only when no Pallas-capable device is available."""
-    return not pallas_native_backend()
 
 
 def pack_leaves(leaves) -> jax.Array:

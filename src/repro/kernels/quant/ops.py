@@ -5,36 +5,48 @@ them INSIDE its single compiled step so the boundary tensor leaves the
 device already quantized (u8 codes + per-channel affine params + the
 carried error-feedback residual), and the codec ships it zero-copy.
 
-Like ``fused_sgd``, ``interpret=None`` autodetects: interpret-mode
-Pallas on CPU, native Mosaic/Triton lowering on TPU/GPU. Arbitrary-rank
-inputs are viewed as ``[rows, channels]`` with channel = last axis, and
-the channel axis is zero-padded to a block multiple (padded channels
-quantize independently and are sliced away).
+Like ``fused_sgd``, ``interpret=None`` follows ``kernels.backend``:
+interpret-mode Pallas on CPU, native lowering on TPU/GPU. Arbitrary-rank
+inputs are viewed as ``[rows, channels]`` with channel = last axis; the
+per-channel range is reduced over all rows here, and the kernels then
+run over row x channel tiles. Both axes are zero-padded to a tile
+multiple (padded rows and channels are sliced away).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quant.kernel import dequantize_kernel, quantize_kernel
+from repro.kernels.backend import default_interpret
+from repro.kernels.quant.kernel import (dequantize_kernel, quantize_kernel,
+                                        tile_shape)
+
+# f32 bit mask keeping the top 16 significant bits of a scale
+SCALE_MASK = 0xFFFFFF00
 
 
-def pallas_native_backend() -> bool:
-    return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
+def exact_product_scale(lo, hi, levels: int):
+    """Per-channel step ``(hi - lo) / levels`` truncated to 16 significant
+    bits, so ``scale * q`` is exact in f32 for every code ``q <= 255``;
+    0 for a degenerate (constant or non-finite) channel."""
+    scale = (hi - lo) * (1.0 / levels)
+    bits = jax.lax.bitcast_convert_type(scale, jnp.uint32)
+    scale = jax.lax.bitcast_convert_type(bits & jnp.uint32(SCALE_MASK),
+                                         jnp.float32)
+    return jnp.where(jnp.isfinite(scale) & (scale > 0), scale, 0.0)
 
 
-def default_interpret() -> bool:
-    # Interpret mode on CPU (no Mosaic/Triton lowering there); native
-    # kernels on TPU/GPU.
-    return not pallas_native_backend()
-
-
-def _pad_cols(a, blk):
-    C = a.shape[-1]
-    pad = (-C) % blk
-    if pad:
-        a = jnp.pad(a, ((0, 0), (0, pad)))
+def _pad2(a, rows: int, cols: int):
+    """Zero-pad a 2D array up to ``[rows, cols]``."""
+    pr, pc = rows - a.shape[0], cols - a.shape[1]
+    if pr or pc:
+        a = jnp.pad(a, ((0, pr), (0, pc)))
     return a
+
+
+def _padded(rows: int, C: int, block: int) -> tuple[int, int]:
+    rb, cb = tile_shape(rows, C, block)
+    return -(-rows // rb) * rb, -(-C // cb) * cb
 
 
 def quantize_ef(x, res=None, *, levels: int = 255, block: int = 128,
@@ -56,6 +68,9 @@ def quantize_ef(x, res=None, *, levels: int = 255, block: int = 128,
     """
     if interpret is None:
         interpret = default_interpret()
+    if not 1 <= levels <= 255:
+        raise ValueError(f"levels must be in [1, 255] for u8 codes, got "
+                         f"{levels}")
     x = jnp.asarray(x, jnp.float32)
     if x.ndim < 1 or x.size == 0:
         raise ValueError(f"quantize_ef needs a non-empty array, got shape "
@@ -65,12 +80,15 @@ def quantize_ef(x, res=None, *, levels: int = 255, block: int = 128,
     z = x if res is None else x + jnp.asarray(res, jnp.float32)
     ok = jnp.isfinite(z).all()
     z2 = z.reshape(-1, C)
-    blk = min(block, C)
-    zp = _pad_cols(z2, blk)
-    q, lo, scale, rout = quantize_kernel(zp, levels=levels,
-                                         block=block, interpret=interpret)
-    return (q[:, :C].reshape(shape), lo[0, :C], scale[0, :C],
-            rout[:, :C].reshape(shape), ok, z)
+    lo = jnp.min(z2, axis=0)
+    scale = exact_product_scale(lo, jnp.max(z2, axis=0), levels)
+    R, Cp = _padded(z2.shape[0], C, block)
+    q, rout = quantize_kernel(_pad2(z2, R, Cp), _pad2(lo[None], 1, Cp),
+                              _pad2(scale[None], 1, Cp), levels=levels,
+                              block=block, interpret=interpret)
+    rows = z2.shape[0]
+    return (q[:rows, :C].reshape(shape), lo, scale,
+            rout[:rows, :C].reshape(shape), ok, z)
 
 
 def dequantize(q, lo, scale, *, block: int = 128,
@@ -87,9 +105,10 @@ def dequantize(q, lo, scale, *, block: int = 128,
     shape = q.shape
     C = shape[-1]
     q2 = q.reshape(-1, C)
-    blk = min(block, C)
-    qp = _pad_cols(q2, blk)
-    lop = _pad_cols(jnp.asarray(lo, jnp.float32).reshape(1, C), blk)
-    scp = _pad_cols(jnp.asarray(scale, jnp.float32).reshape(1, C), blk)
-    x = dequantize_kernel(qp, lop, scp, block=block, interpret=interpret)
-    return x[:, :C].reshape(shape)
+    R, Cp = _padded(q2.shape[0], C, block)
+    x = dequantize_kernel(
+        _pad2(q2, R, Cp),
+        _pad2(jnp.asarray(lo, jnp.float32).reshape(1, C), 1, Cp),
+        _pad2(jnp.asarray(scale, jnp.float32).reshape(1, C), 1, Cp),
+        block=block, interpret=interpret)
+    return x[:q2.shape[0], :C].reshape(shape)

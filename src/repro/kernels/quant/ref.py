@@ -1,14 +1,11 @@
 """Numpy reference for the fused quantize/dequantize kernels.
 
-The op ORDER and dtypes mirror ``kernel.py`` exactly (all f32, ``rint``
-round-half-even, min/max reductions over rows), so tests assert EXACT
-equality for the wire-visible outputs (``q``, ``lo``, ``scale``) against
-the Pallas path. The dequantized value and the residual are the one
-place numpy cannot be bit-exact: XLA contracts ``lo + scale*q`` into an
-FMA (single rounding), so the compiled results may be 1 ulp tighter than
-this two-step version — tests bound that difference at 1 ulp of the
-product and separately assert the compiled residual equals
-``z - dequantize(q, lo, scale)`` exactly (the error-feedback invariant).
+The op ORDER and dtypes mirror ``kernel.py``/``ops.py`` exactly (all
+f32, ``rint`` round-half-even, min/max reductions over rows, the scale
+truncated to 16 significant bits), so tests assert EXACT equality for
+every output against the Pallas path. The truncated scale makes
+``scale * q`` exact, so this two-step ``lo + scale*q`` and a compiler's
+FMA-contracted one round identically.
 """
 from __future__ import annotations
 
@@ -33,6 +30,8 @@ def quantize_ef_reference(x, res=None, *, levels: int = 255):
     hi = np.max(z2, axis=0).astype(np.float32)
     with np.errstate(invalid="ignore", over="ignore", divide="ignore"):
         scale = (hi - lo) * np.float32(1.0 / levels)
+        scale = (scale.view(np.uint32) & np.uint32(0xFFFFFF00)).view(
+            np.float32)
         scale = np.where(np.isfinite(scale) & (scale > 0), scale,
                          np.float32(0)).astype(np.float32)
         safe = np.where(scale > 0, scale, np.float32(1)).astype(np.float32)

@@ -59,7 +59,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_ref, *,
 
 
 def ssd_scan_kernel(xh, dt, A, Bm, Cm, D, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """xh: [B, S, H, P]; dt: [B, S, H]; A, D: [H]; Bm, Cm: [B, S, N].
     Returns y: [B, S, H, P]. S must be a multiple of `chunk`."""
     B, S, H, P = xh.shape

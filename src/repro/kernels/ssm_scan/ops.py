@@ -7,16 +7,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import default_interpret
 from repro.kernels.ssm_scan.kernel import ssd_scan_kernel
 from repro.kernels.ssm_scan.ref import ssd_scan_reference
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def ssd_scan(xh, dt, A, Bm, Cm, D, chunk=128, interpret=True):
+def ssd_scan(xh, dt, A, Bm, Cm, D, chunk=128, interpret=None):
     """xh: [B,S,H,P]; dt: [B,S,H]; A,D: [H]; Bm,Cm: [B,S,N] -> [B,S,H,P].
 
     Sequences are zero-padded to a chunk multiple (zero dt => identity decay
-    contribution, zero input injection: exact)."""
+    contribution, zero input injection: exact). ``interpret=None`` follows
+    ``kernels.backend.default_interpret``."""
+    if interpret is None:
+        interpret = default_interpret()
     B, S, H, P = xh.shape
     pad = (-S) % chunk
     if pad:

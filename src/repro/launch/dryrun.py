@@ -9,7 +9,6 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import mesh_context
 
 from repro.configs import ARCH_IDS, SHAPES, TrainConfig, get_config, get_shape
 from repro.launch import analysis
@@ -35,7 +34,7 @@ def lower_combo(arch: str, shape_id: str, multi_pod: bool, overrides=None):
     chips = mesh.devices.size
     t0 = time.time()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tc = TrainConfig(remat=True)
             step, _ = make_train_step(mesh, cfg, tc)
@@ -68,8 +67,7 @@ def lower_combo(arch: str, shape_id: str, multi_pod: bool, overrides=None):
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    from repro import compat
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll_hlo = analysis.collective_bytes(compiled.as_text())
 
     # roofline from the analytic per-device cost model (raw HLO counts each

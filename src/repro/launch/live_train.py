@@ -29,7 +29,6 @@ Examples:
       --peers coord=10.0.0.1:9000,1=10.0.0.2:9001,2=10.0.0.3:9002
 """
 import argparse
-import os
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -235,9 +234,12 @@ def _report_fleet(res, args):
 
 
 def _report(res, args):
+    import jax
     import numpy as np
     if getattr(args, "chains", 1) > 1:
         return _report_fleet(res, args)
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} ({dev.device_kind}) x {jax.device_count()}")
     print(f"live FTPipeHD run: {args.workers} workers, {args.batches} "
           f"batches, chain={args.chain}, transport={args.transport}, "
           f"hot path={'eager' if args.uncompiled else 'compiled'}"
@@ -276,8 +278,8 @@ def _report(res, args):
 
 def main():
     args = build_parser().parse_args()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax  # noqa: F401  (select platform before any jax usage below)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.run import Run
     from repro.runtime.devices import DeviceSpec

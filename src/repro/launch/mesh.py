@@ -20,25 +20,10 @@ NUM_PODS = 2
 
 
 def axis_types_kwarg(n: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh`` / ``jax.sharding.Mesh``,
-    or ``{}`` on jax versions that predate ``jax.sharding.AxisType`` (whose
-    mesh constructors also reject the kwarg — old meshes are implicitly
-    all-Auto, so omitting it is the same semantics)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def mesh_context(mesh):
-    """Context manager making ``mesh`` the ambient mesh: ``jax.set_mesh``
-    where it exists, else the mesh's own (legacy) context manager — on
-    those versions the ambient mesh is how jit resolves ``P(...)`` axis
-    names, which is all our pipeline steps need from ``set_mesh``."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``axis_types=`` kwarg for ``jax.make_mesh`` / ``jax.sharding.Mesh``:
+    every axis Auto, so jit resolves ``P(...)`` names from the ambient
+    mesh that ``jax.set_mesh`` installs."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,4 +1,3 @@
-from repro.launch.mesh import mesh_context
 """Batched pipelined serving driver: decodes tokens through the stage-
 partitioned model with per-stage KV/SSM caches.
 
@@ -38,7 +37,7 @@ def main():
                                         tensor_parallel=dims[2])
     mesh = make_debug_mesh(*dims)
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: model_lib.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(key)
         layout = (cfg.decoder_slot_layout if cfg.family == "audio"

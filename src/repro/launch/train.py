@@ -1,4 +1,3 @@
-from repro.launch.mesh import mesh_context
 """End-to-end training driver.
 
 Runs the full framework stack (config -> sharded init -> pipelined
@@ -62,7 +61,7 @@ def main():
                      microbatches=args.microbatches, weight_decay=0.0)
 
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(
             lambda k: model_lib.init_params(k, cfg),
             out_shardings=param_shardings(mesh, cfg))(key)

@@ -96,7 +96,7 @@ def attention(p, x, *, cfg: ModelConfig, positions, causal: bool = True,
         out = flash_attention(q.transpose(0, 2, 1, 3),
                               k.transpose(0, 2, 1, 3),
                               v.transpose(0, 2, 1, 3),
-                              causal, window, 128, 128, True)
+                              causal, window, 128, 128)
         out = out.transpose(0, 2, 1, 3).astype(dtype)
     else:
         sel = _kv_select(cfg, q.shape[2], k.shape[2], tp)

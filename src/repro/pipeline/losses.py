@@ -12,8 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.pipeline.sharding import AXIS_STAGE, AXIS_TENSOR, data_axes
 
 VOCAB_AXES = (AXIS_STAGE, AXIS_TENSOR)
@@ -32,7 +30,7 @@ def embed_tokens(mesh, table, tokens, dtype=jnp.bfloat16, data_sharded=True):
         x = tbl[idx] * local[..., None].astype(tbl.dtype)
         return jax.lax.psum(x.astype(jnp.float32), VOCAB_AXES).astype(dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(VOCAB_AXES, None), P(dspec, None)),
         out_specs=P(dspec, None, None))(table, tokens)
@@ -70,7 +68,7 @@ def lm_head_loss(mesh, head_w, y, labels, mask, vocab_size: int = 0,
         den = jax.lax.psum(jnp.sum(mk), dspec)
         return num / jnp.maximum(den, 1.0)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, VOCAB_AXES), P(dspec, None, None),
                   P(dspec, None), P(dspec, None)),
@@ -89,7 +87,7 @@ def lm_head_logits(mesh, head_w, y, data_sharded=True, vocab_size: int = 0):
         col = jax.lax.axis_index(VOCAB_AXES) * V_l + jnp.arange(V_l)
         return jnp.where(col[None, None, :] < V_real, logits, -1e30)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, VOCAB_AXES), P(dspec, None, None)),
         out_specs=P(dspec, None, VOCAB_AXES))(head_w, y)

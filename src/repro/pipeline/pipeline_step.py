@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.models import model as model_lib
 from repro.models import modules
@@ -77,14 +75,6 @@ def pipeline_forward(mesh, cfg: ModelConfig, blocks, x, pad_mask, *,
                                dtype=dtype, causal=causal, window=window,
                                kv_source=kv_in, active=pad_row[j])
                 xx, a = BLOCKS[t].apply(slots[j], xx, ctx)
-                if compat.shard_map_is_legacy():
-                    # Legacy shard_map cannot transpose a shard_map whose
-                    # secondary output (or scan carry feeding it) is
-                    # param-dependent — residual misalignment in jax<0.5
-                    # raises a raw _SpecError. Report the load-balance aux
-                    # without a grad path; aux-loss training needs modern
-                    # jax.
-                    a = jax.lax.stop_gradient(a)
                 aux = aux + a
             return xx, aux
 
@@ -124,7 +114,7 @@ def pipeline_forward(mesh, cfg: ModelConfig, blocks, x, pad_mask, *,
     out_specs = (P(AXIS_STAGE, Bspec, None, None), P(AXIS_STAGE, dspec))
 
     kv_arg = kv_source if kv_source is not None else jnp.zeros((), jnp.float32)
-    y_all, aux_all = compat.shard_map(
+    y_all, aux_all = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)(blocks, x, pad_mask, kv_arg)
     y = y_all[S - 1]
@@ -226,7 +216,7 @@ def pipeline_decode(mesh, cfg: ModelConfig, blocks, x, caches, pos,
     out_specs = (P(AXIS_STAGE, Bspec, None, None), caches_sp)
 
     kv_arg = kv_source if kv_source is not None else jnp.zeros((), jnp.float32)
-    y_all, new_caches = compat.shard_map(
+    y_all, new_caches = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)(blocks, x, pad_mask, caches,
                          jnp.asarray(pos, jnp.int32), kv_arg)
@@ -303,7 +293,7 @@ def pipeline_prefill_chunked(mesh, cfg: ModelConfig, blocks, x, caches,
     in_specs = (blocks_specs, P(Bspec, None, None), P(AXIS_STAGE, None),
                 caches_sp)
     out_specs = (P(AXIS_STAGE, Bspec, None, None), caches_sp)
-    y_all, new_caches = compat.shard_map(
+    y_all, new_caches = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)(blocks, x, pad_mask, caches)
     return y_all[S - 1], new_caches

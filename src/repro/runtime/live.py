@@ -60,12 +60,22 @@ from repro.runtime import netem as netem_mod
 from repro.runtime import protocol
 from repro.runtime.devices import DeviceSpec, WorkloadProfile, uniform_bandwidth
 from repro.runtime.stage_executor import (ChainLayout, StageExecutor,
-                                          aggregate_packed)
+                                          aggregate_packed, stage_device)
 from repro.runtime.transport import (FaultSpec, Heartbeat, Transport,
                                      TransportBase)
 from repro.runtime.workload import LayerChain
 
 COORD = -1          # coordinator control-plane node id on the transport
+
+
+class WorkerError(RuntimeError):
+    """An in-process worker thread raised. The run ends with this error,
+    chained to the worker's own exception, instead of waiting out a
+    segment timeout and treating the silent worker as a failed device."""
+
+    def __init__(self, dev: int, exc: BaseException):
+        super().__init__(f"worker {dev} failed: {exc!r}")
+        self.dev = dev
 
 
 class ChainCollapsedError(RuntimeError):
@@ -301,6 +311,9 @@ class LiveResult:
     #   reported FULLY shipped (its overlap queue drained) — the
     #   coordinator's in-flight-replication bookkeeping, piggybacked on
     #   seg_done; empty in drain mode
+    stage_devices: dict = dataclasses.field(default_factory=dict)
+    #   dev -> ids of the devices holding that in-process worker's newest
+    #   weight buffer at teardown (a crashed worker's last one included)
 
     @property
     def final_partition(self) -> tuple:
@@ -346,6 +359,8 @@ class Worker(threading.Thread):
                               or {"dev": dev, "inc": incarnation})
         self.stop_event = threading.Event()
         self.hb = Heartbeat(transport, dev, COORD, cfg.heartbeat_interval)
+        self.error: Optional[Exception] = None     # what ended run(), if any
+        self.device = stage_device(dev)   # where this stage's buffers live
         self.stash: Optional[VerticalSyncStash] = None
         self.slice_layout = None               # SliceLayout of layer_range
         self.mom_buf = None                    # packed momentum, slice-sized
@@ -419,10 +434,15 @@ class Worker(threading.Thread):
                        for j in self.slice_layout.layer_ids}
         self.layer_range = (a, e)
         self.slice_layout = self.layout.slice(a, e)
-        buf = self.slice_layout.pack(flats)
+        # layers arrive from peers and stores on other devices: move each
+        # to this worker's device before packing
+        buf = self.slice_layout.pack(
+            {j: jax.device_put(flats[j], self.device)
+             for j in range(a, e + 1)})
         self.mom_buf = self.slice_layout.pack(
-            {j: old_mom.get(j, np.zeros(self.layout.layer_size(j),
-                                        np.float32))
+            {j: jax.device_put(
+                old_mom.get(j, np.zeros(self.layout.layer_size(j),
+                                        np.float32)), self.device)
              for j in range(a, e + 1)})
         if self.stash is None:
             self.stash = VerticalSyncStash(buf, version)
@@ -451,7 +471,8 @@ class Worker(threading.Thread):
                 self.chain, self.slice_layout, last=last, lr=self.cfg.lr,
                 momentum=self.cfg.momentum,
                 weight_decay=self.cfg.weight_decay,
-                compiled=self.cfg.compiled, interpret=self.cfg.interpret)
+                compiled=self.cfg.compiled, interpret=self.cfg.interpret,
+                device=self.device)
         return self._execs[key]
 
     def crash(self) -> None:
@@ -490,7 +511,24 @@ class Worker(threading.Thread):
 
     def run(self):
         """Message loop: react to coordinator commands and peer traffic
-        until a ``stop`` (clean shutdown) or ``die`` (injected crash)."""
+        until a ``stop`` (clean shutdown) or ``die`` (injected crash).
+
+        An exception (a compile refusal, device out of memory, a bug)
+        ends the loop and stops the heartbeat. An own-process worker
+        re-raises it, so its process exits non-zero; an in-process worker
+        keeps it in ``error``, where the coordinator picks it up and ends
+        the run with it (``WorkerError``) instead of taking the silent
+        worker for a failed device."""
+        try:
+            self._loop()
+        except Exception as exc:
+            self.error = exc
+            if self.remote:
+                raise
+        finally:
+            self.hb.stop()
+
+    def _loop(self):
         greeted = not self.announce
         last_hello = 0.0
         self.hb.start()
@@ -546,7 +584,6 @@ class Worker(threading.Thread):
                 self._maybe_die(msg.payload)
             elif k == "stop":
                 break
-        self.hb.stop()
 
     # --------------------------- segment exec ----------------------------
 
@@ -1259,13 +1296,22 @@ class Coordinator:
         return {dev: list(self._dev_addrs[dev]) for dev in worker_ids
                 if dev in self._dev_addrs}
 
+    def _recv(self):
+        """One poll of the COORD inbox — the only way the coordinator
+        receives, so every wait also notices a local worker that raised
+        and ends the run with its exception."""
+        for w in self.workers.values():
+            if w.error is not None:
+                raise WorkerError(w.dev, w.error) from w.error
+        return self.transport.recv(COORD, timeout=self.cfg.poll)
+
     def _collect(self, kinds: set, expect: int, timeout: float,
                  on_msg=None) -> int:
         """Drain COORD inbox until `expect` messages of `kinds` arrived."""
         got = 0
         deadline = time.monotonic() + timeout
         while got < expect and time.monotonic() < deadline:
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is None:
                 continue
             self._absorb(msg)
@@ -1459,7 +1505,7 @@ class Coordinator:
                 self._log(f"joiner dev{d} never said hello — giving up")
             if not waiting:
                 return
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
 
@@ -1484,7 +1530,7 @@ class Coordinator:
                                               5 * self.proto.detect_timeout)
             while dev not in self._cap_acks \
                     and time.monotonic() < deadline:
-                msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+                msg = self._recv()
                 if msg is not None:
                     self._absorb(msg)
         ack = self._cap_acks.pop(dev, None)
@@ -1603,7 +1649,7 @@ class Coordinator:
                                        else self.cfg.segment_timeout)
         while len(heard) < len(self.remote_devs) \
                 and time.monotonic() < deadline:
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is None:
                 continue
             self._absorb(msg)
@@ -1727,7 +1773,7 @@ class Coordinator:
                     if d not in self._ready_acks[version]]
 
         while _pending() and time.monotonic() < deadline:
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
             # fail fast on in-flight death: a pending worker that has gone
@@ -1833,7 +1879,7 @@ class Coordinator:
                 # there is no data-plane retransmission): hand it to the
                 # stall/restart path rather than crashing the run
                 return False, {"suspects": []}, self._committed
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
             suspects = [dev for dev in worker_ids
@@ -1853,7 +1899,7 @@ class Coordinator:
                                                if dev != 0}
         deadline = time.monotonic() + max(10 * self.proto.probe_rtt, 0.3)
         while time.monotonic() < deadline:
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is None:
                 continue
             self._absorb(msg)
@@ -1888,7 +1934,7 @@ class Coordinator:
             if time.monotonic() - last_sent > resend_every:
                 _send_aborts()
                 last_sent = time.monotonic()
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
         self.abort_event.clear()
@@ -1967,7 +2013,7 @@ class Coordinator:
                          "version": version, "stage": i,
                          "wire": self.wire.to_payload(), "addrs": addrs})
                 last_sent = time.monotonic()
-            msg = self.transport.recv(COORD, timeout=self.cfg.poll)
+            msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
         self._log(f"re-adopted workers {remote} @version {version}")
@@ -2070,7 +2116,10 @@ class Coordinator:
             recoveries=self.recoveries, admissions=self.admissions,
             replica_report=self.global_store.nbytes_report(),
             final_flats=self.final_flats,
-            shipped_gens=dict(self.shipped_gens))
+            shipped_gens=dict(self.shipped_gens),
+            stage_devices={
+                dev: sorted(d.id for d in w.stash.newest().devices())
+                for dev, w in self.workers.items() if w.stash is not None})
 
     def _run_protocol(self, est, part, partitions, worker_ids, profile,
                       state):

@@ -562,8 +562,21 @@ class SocketTransport(TransportBase):
 
 # ======================= multi-process harness ===========================
 
+def _select_platform(platform: Optional[str]) -> None:
+    """Process bring-up shared by the worker and coordinator entry points:
+    pin the JAX platform the parent chose (spawned children), or keep the
+    host's own (one ``--role`` process per host), then turn on the
+    compile cache — both before this process compiles anything."""
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
+    if platform is not None:
+        jax.config.update("jax_platforms", platform)
+    enable_compile_cache()
+
+
 def worker_main(dev: int, addr_of: Dict[int, Addr], spec, cfg,
-                incarnation: int = 0) -> None:
+                incarnation: int = 0, platform: Optional[str] = None) -> None:
     """Entry point of one worker PROCESS (spawned by ``run_tcp_training``
     or run per-host via ``launch/live_train.py --role worker``).
 
@@ -577,9 +590,12 @@ def worker_main(dev: int, addr_of: Dict[int, Addr], spec, cfg,
     and this process's listen address, the coordinator admits it at the
     next control point (see ``live.Coordinator``), and a ``die`` addressed
     to an older incarnation is ignored instead of SIGKILLing the fresh
-    process."""
-    import os
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    process.
+
+    ``platform`` pins the JAX platform (``run_tcp_training`` passes its
+    own); None keeps this host's default, so a ``--role worker`` process
+    owns its host's accelerator."""
+    _select_platform(platform)
     from repro.runtime.devices import DeviceSpec
     from repro.runtime.live import COORD, Worker
 
@@ -649,16 +665,16 @@ def _spawn_with_pythonpath(procs) -> None:
 
 def coordinator_main(spec, cfg, addr_of: Dict[int, Addr],
                      manifest_doc: Optional[dict] = None,
-                     resume_state: Optional[dict] = None) -> None:
+                     resume_state: Optional[dict] = None,
+                     platform: Optional[str] = None) -> None:
     """Entry point of a coordinator PROCESS that can itself be SIGKILLed:
     hosts the control plane (``COORD``) plus worker device 0 on
     ``addr_of[0]``, with every other worker expected to run as its own
     process (``worker_main``). The failover demo runs the coordinator
     through this so killing it severs sockets mid-stream; a relaunch with
     the run manifest (``run.Run.resume``) then re-adopts the surviving
-    worker processes."""
-    import os
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    worker processes. ``platform`` as in ``worker_main``."""
+    _select_platform(platform)
     from repro.runtime.live import COORD, Coordinator
 
     chain, batches = spec.build()
@@ -704,17 +720,32 @@ def run_tcp_training(spec, cfg, *, host: str = "127.0.0.1",
     probing races when chains launch concurrently). When the chain
     collapses below ``cfg.min_workers`` the raised ``ChainCollapsedError``
     is annotated with the worker exit codes before propagating, so the
-    fleet monitor sees the same post-mortem a ``LiveResult`` would carry."""
+    fleet monitor sees the same post-mortem a ``LiveResult`` would carry.
+
+    A chip belongs to one process, so this localhost harness runs only
+    where this process holds no accelerator; its children are told the
+    parent's platform explicitly."""
     import multiprocessing as mp
+
+    import jax
 
     from repro.runtime.live import (COORD, ChainCollapsedError, Coordinator)
 
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"run_tcp_training spawns its worker processes on this host, "
+            f"but this process holds the {platform} accelerator and a chip "
+            f"serves one process: use the queue transport (worker threads "
+            f"in this process), or start one --role worker per "
+            f"accelerator host")
     if addr_of is None:
         addr_of = cluster_addresses(cfg.num_workers, host)
     ctx = mp.get_context("spawn")
     history: Dict[int, list] = {
         dev: [ctx.Process(target=worker_main,
-                          args=(dev, addr_of, spec, cfg), daemon=True)]
+                          args=(dev, addr_of, spec, cfg, 0, platform),
+                          daemon=True)]
         for dev in range(1, cfg.num_workers)}
     _spawn_with_pythonpath([ps[0] for ps in history.values()])
 
@@ -725,7 +756,8 @@ def run_tcp_training(spec, cfg, *, host: str = "127.0.0.1",
         if dev not in child_addr:
             child_addr[dev] = (host, free_port(host))
         p = ctx.Process(target=worker_main,
-                        args=(dev, child_addr, spec, cfg, incarnation),
+                        args=(dev, child_addr, spec, cfg, incarnation,
+                              platform),
                         daemon=True)
         history.setdefault(dev, []).append(p)
         _spawn_with_pythonpath([p])

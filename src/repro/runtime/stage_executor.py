@@ -44,13 +44,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.fused_sgd.ops import (default_interpret, fused_sgd,
-                                         pack_leaves, pallas_native_backend,
-                                         unpack_leaves)
+from repro.kernels.backend import default_interpret, pallas_native_backend
+from repro.kernels.fused_sgd.ops import fused_sgd, pack_leaves, unpack_leaves
 from repro.kernels.quant.ops import dequantize as quant_dequantize
 from repro.kernels.quant.ops import quantize_ef
 from repro.optim.sgd import sgd_update
 from repro.runtime.qtensor import DeviceQuantized
+
+
+def stage_device(dev: int):
+    """The accelerator that worker ``dev`` keeps its buffers and runs its
+    steps on: ``jax.devices()[dev mod n]``. Keyed by the device id, not
+    the pipeline stage, so a worker stays on its chip when §III-F
+    recovery renumbers the stages; with one device every worker shares
+    it."""
+    devices = jax.devices()
+    return devices[dev % len(devices)]
 
 
 def aggregate_packed(bufs) -> jnp.ndarray:
@@ -184,8 +193,9 @@ class StageExecutor:
     def __init__(self, chain, slice_layout: SliceLayout, *, last: bool,
                  lr: float, momentum: float = 0.9,
                  weight_decay: float = 4e-5, compiled: bool = True,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None, device=None):
         self.slice = slice_layout
+        self.device = device     # inbound tensors move here (None: stay)
         self.last = last
         self.compiled = compiled
         ids = slice_layout.layer_ids
@@ -299,18 +309,17 @@ class StageExecutor:
             self._forward_q = fwd_q_fn
             self._step_q = step_q_fn
 
-    @staticmethod
-    def _coerce(x):
-        """Wire value -> jit input. Exact tensors become f32 arrays; a
-        ``DeviceQuantized`` becomes a (q, lo, scale) device triple that
-        the compiled call dequantizes via the fused kernel — this is the
-        dequantization boundary of the wire-compression tiers
-        (``runtime/codec.py``): tags 10-12 already decoded to f32, tag 13
-        dequantizes on-device HERE, inside the single jitted step."""
+    def _coerce(self, x):
+        """Wire value -> jit input on this stage's device. Exact tensors
+        become f32 arrays; a ``DeviceQuantized`` becomes a (q, lo, scale)
+        device triple that the compiled call dequantizes via the fused
+        kernel — this is the dequantization boundary of the
+        wire-compression tiers (``runtime/codec.py``): tags 10-12 already
+        decoded to f32, tag 13 dequantizes on-device HERE, inside the
+        single jitted step."""
         if isinstance(x, DeviceQuantized):
-            q, lo, scale = x.arrays()
-            return (jnp.asarray(q), jnp.asarray(lo), jnp.asarray(scale))
-        return jnp.asarray(x, jnp.float32)
+            return tuple(jax.device_put(a, self.device) for a in x.arrays())
+        return jax.device_put(x, self.device).astype(jnp.float32)
 
     def forward(self, buf, x, batch=None):
         """Run the slice forward under packed weights ``buf``: activation

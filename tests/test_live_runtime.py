@@ -3,6 +3,7 @@ async-semantics parity with the sequential oracle, fault recovery, and the
 replication/stash plumbing.
 """
 import threading
+import time
 
 import jax
 import numpy as np
@@ -468,3 +469,74 @@ def test_emulated_heterogeneity_repartitions_away_from_slow_worker():
     final = np.diff(np.concatenate([[-1], np.asarray(res.final_partition)]))
     assert final[2] <= min(final[0], final[1])
     assert res.capacities[2] > 2.0            # measured it as slow
+
+
+# ===================== worker errors and device placement =================
+
+@pytest.mark.live
+def test_worker_exception_ends_run_with_its_error(monkeypatch):
+    """A worker thread that raises (a compile refusal, device OOM, a bug)
+    ends the run with that error at once: its heartbeat stops and the
+    coordinator raises ``WorkerError`` instead of waiting out
+    ``segment_timeout`` and restarting around a silent worker."""
+    from repro.runtime import stage_executor
+    from repro.runtime.live import WorkerError
+
+    boom = RuntimeError("refused by the compiler")
+    real_step = stage_executor.StageExecutor.step
+    calls = []
+
+    def failing_step(self, *a, **kw):
+        calls.append(1)
+        if len(calls) == 5:
+            raise boom
+        return real_step(self, *a, **kw)
+
+    monkeypatch.setattr(stage_executor.StageExecutor, "step", failing_step)
+    chain, data = _chain_and_data()
+    t0 = time.monotonic()
+    with pytest.raises(WorkerError) as info:
+        run_live_training(chain, data, LiveConfig(
+            num_workers=3, num_batches=12, protocol=_quiet_protocol(),
+            lr=0.1, segment_timeout=120.0))
+    assert time.monotonic() - t0 < 30.0
+    assert info.value.__cause__ is boom
+    assert not any(t.name.startswith("worker-") and t.is_alive()
+                   for t in threading.enumerate())
+
+
+@pytest.mark.live
+def test_workers_keep_their_buffers_on_their_own_device(monkeypatch):
+    """Worker ``dev`` holds its stage buffers on ``jax.devices()[dev % n]``
+    (the host's virtual devices here), and training matches the same run
+    with every worker pinned to device 0. A §III-F kill redistributes
+    between devices and every survivor stays on its own. (Where a run
+    resumes after a kill depends on what had committed when the failure
+    was detected, so only the losses before the kill are compared there.)"""
+    from repro.runtime import live
+
+    devices = jax.devices()
+    assert len(devices) >= 4
+
+    def run(kill=None):
+        chain, data = _chain_and_data()
+        return run_live_training(chain, data, LiveConfig(
+            num_workers=4, num_batches=20,
+            protocol=_quiet_protocol(chain_every=5, global_every=10,
+                                     detect_timeout=0.4),
+            lr=0.1, kill=kill))
+
+    spread, killed = run(), run(kill=(1, 12))
+    monkeypatch.setattr(live, "stage_device", lambda dev: devices[0])
+    pinned = run()
+    own = {d: [devices[d].id] for d in range(4)}
+    assert spread.stage_devices == own
+    assert killed.stage_devices == own
+    assert all(ids == [devices[0].id]
+               for ids in pinned.stage_devices.values())
+    np.testing.assert_allclose(spread.losses, pinned.losses, rtol=1e-6)
+    assert [r["failed"] for r in killed.recoveries] == [[1]]
+    assert len(killed.final_partition) == 3
+    assert np.isfinite(killed.losses).all()
+    np.testing.assert_allclose(killed.losses[:12], spread.losses[:12],
+                               rtol=1e-6)

@@ -371,3 +371,16 @@ class TestPerKindStats:
         # mutating the copy must not touch the transport's counters
         wire["kind_bytes"]["act"] = -1
         assert run.status()["wire"]["kind_bytes"]["act"] > 0
+
+
+def test_run_tcp_training_refuses_when_this_process_holds_a_chip(
+        monkeypatch):
+    """A chip serves one process: the localhost harness would have its
+    spawned workers fight this process for it, so it refuses up front and
+    points to the queue transport."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = WorkloadSpec(kind="mlp", num_layers=4)
+    with pytest.raises(RuntimeError, match="queue transport"):
+        run_tcp_training(spec, LiveConfig(num_workers=2, num_batches=2))

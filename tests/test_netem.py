@@ -58,7 +58,9 @@ class TestShaperProperties:
             last_arrival = arrival
         assert delivered + dropped == len(sizes)
         stats = sh.stats
-        assert stats["shaped"] == delivered
+        # a transparent link (no latency, jitter, rate, loss or partition)
+        # takes the fast path: delivered at once, never counted as shaped
+        assert stats["shaped"] == (0 if link.is_transparent() else delivered)
         assert stats["netem_dropped"] + stats["netem_blocked"] == dropped
         sh.close()
 
@@ -246,13 +248,17 @@ def test_act_outrunning_segment_message_is_buffered_not_dropped():
         netem=NetemSpec(default=LinkSpec(),
                         links={(-1, 1): LinkSpec(latency=0.3)},
                         colocated=()))
-    t0 = time.monotonic()
     res = run_live_training(chain, data, cfg)
-    wall = time.monotonic() - t0
     assert res.recoveries == []
     assert not np.isnan(res.losses).any()
+    # a stalled segment waits out segment_timeout and restarts
+    assert not [e for _, e in res.events if "stall" in e], res.events
     # 4 segment boundaries x 0.3s control-link delay, nothing else slow:
-    # far below even ONE stall-restart cycle (segment_timeout=3.0)
+    # far below even ONE stall-restart cycle (segment_timeout=3.0). Timed
+    # over the batches themselves: the stage compiles before the first
+    # commit take what a loaded host gives them
+    commits = res.commit_times
+    wall = commits[max(commits)] - commits[min(commits)]
     assert wall < 3.0, f"pipeline stalled under asymmetric link delay: " \
                        f"{wall:.1f}s"
 

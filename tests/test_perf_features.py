@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.launch.mesh import axis_types_kwarg, mesh_context
+from repro.launch.mesh import axis_types_kwarg
 from repro.models import model as M
 from repro.pipeline.pipeline_step import make_prefill_step, make_train_step
 from repro.configs.base import TrainConfig
@@ -44,7 +44,7 @@ def test_chunked_prefill_matches_full_forward(mesh, arch, tp, flash):
     toks = jax.random.randint(jax.random.fold_in(KEY, 1), (B, S), 0,
                               cfg.vocab_size)
     full, _, _ = M.sequential_lm_forward(params, cfg, toks)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         caches = M.init_caches(cfg, batch=B, cache_len=S, dtype=jnp.float32)
         pf = jax.jit(make_prefill_step(mesh, cfg, seq_chunks=4))
         logits, new_caches = pf(params, {"tokens": toks}, caches)
@@ -60,7 +60,7 @@ def test_chunked_prefill_chunk_count_invariance(mesh):
     B, S = 4, 64
     toks = jax.random.randint(KEY, (B, S), 0, cfg.vocab_size)
     outs = []
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for chunks in (2, 4, 8):
             caches = M.init_caches(cfg, batch=B, cache_len=S,
                                    dtype=jnp.float32)
@@ -82,7 +82,7 @@ def test_chunked_prefill_caches_usable_for_decode(mesh):
     # oracle: full forward over everything
     full, _, _ = M.sequential_lm_forward(params, cfg, toks)
     from repro.pipeline.pipeline_step import make_serve_step
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         caches = M.init_caches(cfg, batch=B, cache_len=total,
                                dtype=jnp.float32)
         pf = jax.jit(make_prefill_step(mesh, cfg, seq_chunks=4))
@@ -117,7 +117,7 @@ def test_extra_data_axis_training(mesh_extra):
     toks = jax.random.randint(KEY, (8, 16), 0, cfg.vocab_size)
     labels = jax.random.randint(jax.random.fold_in(KEY, 1), (8, 16), 0,
                                 cfg.vocab_size)
-    with mesh_context(mesh_extra):
+    with jax.set_mesh(mesh_extra):
         loss_fn = make_loss_fn(mesh_extra, cfg, num_microbatches=2,
                                remat=False)
         (total, metrics), grads = jax.jit(
@@ -156,7 +156,7 @@ def test_bf16_grads_training_still_learns(mesh):
     tc = TrainConfig(learning_rate=0.02, optimizer="adam", microbatches=2,
                      weight_decay=0.0, bf16_grads=True)
     from repro.pipeline.sharding import param_shardings
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: M.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(KEY)
         step_fn, _ = make_train_step(mesh, cfg, tc)

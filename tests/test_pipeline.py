@@ -3,7 +3,7 @@ decode, whisper two-phase, stash/aggregation semantics. Runs on an 8-host-
 device (data=2, stage=2, tensor=2) mesh."""
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import axis_types_kwarg, mesh_context
+from repro.launch.mesh import axis_types_kwarg
 import numpy as np
 import pytest
 
@@ -45,7 +45,7 @@ def test_pipeline_loss_and_grads_match_sequential(mesh, arch, tp):
                               cfg.vocab_size)
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (4, 16), 0,
                                 cfg.vocab_size)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         loss_fn = make_loss_fn(mesh, cfg, num_microbatches=2, remat=True)
         (total, metrics), grads = jax.jit(
             jax.value_and_grad(loss_fn, has_aux=True))(
@@ -74,7 +74,7 @@ def test_pipeline_decode_matches_sequential(mesh, arch, tp):
         lg, cc = M.sequential_decode_step(params, cfg, toks[:, t:t + 1], cc,
                                           jnp.int32(t))
         seq_logits.append(lg)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         serve = jax.jit(make_serve_step(mesh, cfg, num_microbatches=2))
         c2 = M.init_caches(cfg, batch=B, cache_len=W, dtype=jnp.float32)
         for t in range(T):
@@ -93,7 +93,7 @@ def test_whisper_pipeline_matches_sequential(mesh):
     logits_ref, _, _ = M.sequential_encdec_forward(params, cfg, frames, toks)
     lp = jax.nn.log_softmax(logits_ref.astype(jnp.float32))
     ref = -jnp.mean(jnp.take_along_axis(lp, toks[..., None], -1)[..., 0])
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         loss_fn = make_loss_fn(mesh, cfg, num_microbatches=2, remat=False)
         (_, metrics), _ = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
             params, {"frames": frames, "tokens": toks, "labels": toks})
@@ -108,7 +108,7 @@ def test_microbatch_count_invariance(mesh):
     params = M.init_params(KEY, cfg)
     toks = jax.random.randint(KEY, (8, 16), 0, cfg.vocab_size)
     batch = {"tokens": toks, "labels": toks}
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         losses = []
         for m in (1, 2, 4):
             loss_fn = make_loss_fn(mesh, cfg, num_microbatches=m, remat=False)
@@ -130,7 +130,7 @@ def test_train_step_stash_and_aggregation(mesh):
     params = M.init_params(KEY, cfg)
     toks = jax.random.randint(KEY, (4, 16), 0, cfg.vocab_size)
     batch = {"tokens": toks, "labels": toks}
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step_fn, _ = make_train_step(mesh, cfg, tc)
         state = step_fn.init_state(params)
         jstep = jax.jit(step_fn)
@@ -164,7 +164,7 @@ def test_long_context_window_decode(mesh):
         lg, cc = M.sequential_decode_step(params, cfg, toks[:, t:t + 1], cc,
                                           jnp.int32(t))
         seq_logits.append(lg)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         serve = jax.jit(make_serve_step(mesh, cfg, window=W))
         c2 = M.init_caches(cfg, batch=B, cache_len=W, dtype=jnp.float32)
         for t in range(T):

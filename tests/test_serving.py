@@ -2,7 +2,7 @@
 exactly the tokens a standalone generation produces."""
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import axis_types_kwarg, mesh_context
+from repro.launch.mesh import axis_types_kwarg
 import numpy as np
 import pytest
 
@@ -99,7 +99,7 @@ def test_per_slot_positions_in_pipeline_decode():
     # all slots at the same position vector == scalar-pos behaviour
     caches_a = M.init_caches(cfg, batch=B, cache_len=W, dtype=jnp.float32)
     caches_b = M.init_caches(cfg, batch=B, cache_len=W, dtype=jnp.float32)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         serve = jax.jit(make_serve_step(mesh, cfg, num_microbatches=2))
         for t in range(5):
             la, caches_a = serve(params, toks[:, t:t+1], caches_a,

@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.fused_sgd.ops import (default_interpret, fused_sgd,
-                                         pallas_native_backend)
+from repro.kernels.backend import default_interpret, pallas_native_backend
+from repro.kernels.fused_sgd.ops import fused_sgd
 from repro.optim.sgd import sgd_init, sgd_update
 from repro.runtime.stage_executor import ChainLayout, StageExecutor
 from repro.runtime.workload import classification_batches, mlp_chain

@@ -5,7 +5,7 @@ import tempfile
 
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import axis_types_kwarg, mesh_context
+from repro.launch.mesh import axis_types_kwarg
 import numpy as np
 import pytest
 
@@ -113,7 +113,7 @@ class TestVocabParallel:
         V_real, V_pad, d = 50, 64, 16
         table = jax.random.normal(KEY, (V_pad, d))
         toks = jax.random.randint(KEY, (4, 8), 0, V_real)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             x = LL.embed_tokens(mesh, table, toks, jnp.float32)
         np.testing.assert_allclose(np.asarray(x), np.asarray(table[toks]),
                                    atol=1e-5)
@@ -122,7 +122,7 @@ class TestVocabParallel:
         labels = jax.random.randint(jax.random.fold_in(KEY, 2), (4, 8), 0,
                                     V_real)
         mask = jnp.ones((4, 8), jnp.float32)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             loss = LL.lm_head_loss(mesh, head, y, labels, mask,
                                    vocab_size=V_real)
         logits = (y @ head)[..., :V_real]
@@ -135,7 +135,7 @@ class TestVocabParallel:
         V_real, V_pad, d = 50, 64, 16
         head = jax.random.normal(KEY, (d, V_pad))
         y = jax.random.normal(KEY, (4, 1, d))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             logits = LL.lm_head_logits(mesh, head, y, vocab_size=V_real)
         assert np.asarray(logits)[..., V_real:].max() <= -1e29
 
@@ -183,13 +183,12 @@ class TestCostModel:
         params = M.init_params(KEY, cfg)
         B, T = 8, 128
         toks = jnp.zeros((B, T), jnp.int32)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             loss_fn = make_loss_fn(mesh, cfg, num_microbatches=4, remat=False,
                                    unroll=True)
             co = jax.jit(jax.value_and_grad(loss_fn, has_aux=True)).lower(
                 params, {"tokens": toks, "labels": toks}).compile()
-        from repro import compat
-        flops_hlo = compat.cost_analysis(co)["flops"]
+        flops_hlo = co.cost_analysis()["flops"]
         combo = CM.Combo(cfg, InputShape("t", T, B, "train"))
         combo.D, combo.B_loc, combo.M, combo.mb = 2, 4, 4, 1
         combo.S, combo.Tp, combo.ticks = 2, 2, 5

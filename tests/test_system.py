@@ -2,7 +2,7 @@
 the full FTPipeHD protocol survives a mid-training failure."""
 import jax
 import jax.numpy as jnp
-from repro.launch.mesh import axis_types_kwarg, mesh_context
+from repro.launch.mesh import axis_types_kwarg
 import numpy as np
 import pytest
 
@@ -25,7 +25,7 @@ def _train(mesh, cfg, steps=40, lr=0.02, opt="adam"):
     tc = TrainConfig(learning_rate=lr, optimizer=opt, microbatches=2,
                      weight_decay=0.0)
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: M.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(key)
         step_fn, _ = make_train_step(mesh, cfg, tc)
@@ -89,7 +89,7 @@ def test_checkpoint_recovery_roundtrip(mesh, tmp_path):
                                            vocab_size=256)
     tc = TrainConfig(learning_rate=0.02, optimizer="adam", microbatches=2)
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: M.init_params(k, cfg),
                          out_shardings=param_shardings(mesh, cfg))(key)
         step_fn, _ = make_train_step(mesh, cfg, tc)

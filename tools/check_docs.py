@@ -60,6 +60,7 @@ CLI_HINTS = {
     "fault_tolerance_demo.py": "examples/fault_tolerance_demo.py",
     "bench_wan_validation.py": "benchmarks/bench_wan_validation.py",
     "check_bench.py": "tools/check_bench.py",
+    "chip_smoke.py": "chip_smoke.py",
 }
 
 
@@ -78,6 +79,8 @@ def flags_of(py_path: Path) -> set[str]:
 
 def all_repo_flags() -> set[str]:
     flags: set[str] = set()
+    for py in REPO.glob("*.py"):          # root-level scripts
+        flags |= flags_of(py)
     for sub in ("src", "benchmarks", "examples", "tools"):
         for py in (REPO / sub).rglob("*.py"):
             flags |= flags_of(py)
