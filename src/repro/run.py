@@ -291,16 +291,16 @@ class Run:
 
             {"state", "transport",
              "fleet":  {chains, live, rounds, aggregate_every, ...},
-             "chains": {chain_id: {"progress", "wire", "membership"}}}
+             "chains": {chain_id: {"progress", "wire", "membership",
+                                   "stages", "control"}}}
 
         A single-chain run is reported as a fleet of one (its chain id is
         0). For durable runs the manifest's last committed batch rides in
         ``chains[i]["progress"]["last_committed_manifest"]`` (readable by
-        ANY process, not just the owning one).
-
-        DEPRECATED top-level aliases — ``batches_done``, ``wire``,
-        ``last_committed`` — mirror chain 0 / the fleet max for one
-        release; read the nested schema instead."""
+        ANY process, not just the owning one). Each chain also carries
+        the running totals of its spans: ``stages`` (per worker: busy,
+        wait and host seconds, batches) and ``control`` (control points
+        and their drain, replication and refill seconds)."""
         if self._thread is None:
             state = "created"
         elif self._thread.is_alive():
@@ -332,19 +332,6 @@ class Run:
             if 0 in out["chains"]:
                 out["chains"][0]["progress"]["last_committed_manifest"] = (
                     manifest.last_committed if manifest is not None else -1)
-        # ---- deprecated flat aliases (one release; docs/operations.md) --
-        out["batches_done"] = max(
-            (c["progress"]["batches_done"] for c in out["chains"].values()),
-            default=0)
-        wire0 = out["chains"].get(0, {}).get("wire")
-        if wire0 is not None:
-            out["wire"] = {"bytes": wire0.get("bytes", 0),
-                           "kind_bytes": dict(wire0.get("kind_bytes", {})),
-                           "kind_msgs": dict(wire0.get("kind_msgs", {}))}
-        if run_dir and self.config.fleet.chains == 1:
-            manifest = RunManifest.try_load(run_dir)
-            out["last_committed"] = (manifest.last_committed
-                                     if manifest is not None else -1)
         if self._error is not None:
             out["error"] = repr(self._error)
         return out

@@ -58,6 +58,7 @@ from repro.core.partition import PartitionResult, uniform_partition
 from repro.core.redistribution import RedistributionPlan
 from repro.runtime import netem as netem_mod
 from repro.runtime import protocol
+from repro.runtime.spans import Span
 from repro.runtime.devices import DeviceSpec, WorkloadProfile, uniform_bandwidth
 from repro.runtime.stage_executor import (ChainLayout, StageExecutor,
                                           aggregate_packed, stage_device)
@@ -314,6 +315,17 @@ class LiveResult:
     stage_devices: dict = dataclasses.field(default_factory=dict)
     #   dev -> ids of the devices holding that in-process worker's newest
     #   weight buffer at teardown (a crashed worker's last one included)
+    stage_stats: list = dataclasses.field(default_factory=list)
+    #   [{seg_id, dev, t_done, nb, busy_s, wait_s, host_s}], one per
+    #   seg_done of a segment the coordinator was running: t_done on the
+    #   coordinator's clock (as commit_times), the rest the worker's
+    #   fwd+step, wait and other host seconds of that segment
+    control_points: list = dataclasses.field(default_factory=list)
+    #   [{batch, t, drain_s, replicate_s, refill_s}], one per control
+    #   point between two segments that completed: the previous
+    #   segment's last commit to its last seg_done, the replication, and
+    #   the end of the control work to the first commit of the segment
+    #   from ``batch`` (at ``t``, the coordinator's clock)
 
     @property
     def final_partition(self) -> tuple:
@@ -361,6 +373,7 @@ class Worker(threading.Thread):
         self.hb = Heartbeat(transport, dev, COORD, cfg.heartbeat_interval)
         self.error: Optional[Exception] = None     # what ended run(), if any
         self.device = stage_device(dev)   # where this stage's buffers live
+        self.span = f"ftp.w{dev}"         # prefix of this worker's spans
         self.stash: Optional[VerticalSyncStash] = None
         self.slice_layout = None               # SliceLayout of layer_range
         self.mom_buf = None                    # packed momentum, slice-sized
@@ -558,11 +571,17 @@ class Worker(threading.Thread):
                 # message (independent link delays); _dispatch buffers it
                 self._dispatch(msg)
             elif k == "replicate":
-                self._do_replicate(msg.payload)
+                with Span(f"{self.span}.replicate",
+                          batch=msg.payload["batch"]):
+                    self._do_replicate(msg.payload)
             elif k in ("repart", "recover"):
-                self._do_refit(msg.payload)
+                with Span(f"{self.span}.refit",
+                          version=msg.payload["version"]):
+                    self._do_refit(msg.payload)
             elif k == "install":
-                self._do_install(msg.payload)
+                with Span(f"{self.span}.refit",
+                          version=msg.payload.get("version", 0)):
+                    self._do_install(msg.payload)
             elif k == "fetch_req":
                 self._serve_fetch(msg)
             elif k in ("chain_put", "ov_chain_put"):
@@ -616,13 +635,21 @@ class Worker(threading.Thread):
         elif k == "stop":
             self.stop_event.set()
 
-    def _await(self, store: dict, key: int):
-        while key not in store:
-            if self.stop_event.is_set() or self.abort_event.is_set():
-                return None
-            msg = self.transport.recv(self.dev, timeout=self.cfg.poll)
-            if msg is not None:
-                self._dispatch(msg)
+    def _await(self, store: dict, key: int, waited: dict):
+        """The act or grad of batch ``key``; None once the run stops or
+        the segment aborts. Time spent waiting for it is a ``wait`` span,
+        added to ``waited["wait_s"]``."""
+        if key not in store:
+            with Span(f"{self.span}.wait", waited, "wait_s",
+                      seg=self._seg_id):
+                while key not in store:
+                    if self.stop_event.is_set() \
+                            or self.abort_event.is_set():
+                        return None
+                    msg = self.transport.recv(self.dev,
+                                              timeout=self.cfg.poll)
+                    if msg is not None:
+                        self._dispatch(msg)
         return store.pop(key)
 
     def _learn_routes(self, spec: dict) -> None:
@@ -636,6 +663,7 @@ class Worker(threading.Thread):
                     self.transport.add_route(int(d), (a[0], int(a[1])))
 
     def _run_segment(self, spec: dict):
+        t_enter = time.perf_counter()
         if self.remote:      # any past abort is over once new work arrives
             self.abort_event.clear()
         self._learn_routes(spec)
@@ -670,8 +698,11 @@ class Worker(threading.Thread):
             next_fwd[idx] = (b0 + ops[idx].batch if ops[idx].kind == "fwd"
                              else next_fwd[idx + 1])
 
-        batch_times: dict[int, float] = {}     # fwd+bwd wall time per batch
+        # fwd+step seconds per batch (Eq. 1's input), their sum over the
+        # segment, and the seconds spent waiting for an act or grad
+        batch_times: dict[int, float] = {}
         busy, done_ops = 0.0, 0
+        waited = {"wait_s": 0.0}
         for idx, op in enumerate(ops):
             if self.stop_event.is_set() or self.abort_event.is_set():
                 break
@@ -684,29 +715,31 @@ class Worker(threading.Thread):
                 if stage == 0:
                     x = self.chain.input_of(self.data_fn(gb))
                 else:
-                    x = self._await(self._acts, op.batch)
+                    x = self._await(self._acts, op.batch, waited)
                     if x is None:
                         break
                 ver = sched.version_for_batch(gb, n)
                 ver_buf = self.stash.get(ver)
-                t0 = time.perf_counter()
-                if last:
-                    loss = ex.forward(ver_buf, x, self.data_fn(gb))
-                    jax.block_until_ready(loss)
-                    self.transport.send(self.dev, COORD, "loss",
-                                        (gb, float(loss)))
-                elif fused:
-                    y, self._act_res = ex.forward_q(ver_buf, x,
-                                                    self._act_res)
-                    jax.block_until_ready(self._act_res)
-                else:
-                    y = ex.forward(ver_buf, x)
-                    jax.block_until_ready(y)
-                # the backward recomputes the forward from exactly this
-                # (version buffer, input) pair — same residuals the old
-                # vjp-closure path kept alive, without storing them
-                self._fwd_ctx[op.batch] = (ver_buf, x)
-                dt = time.perf_counter() - t0
+                with Span(f"{self.span}.fwd", seg=self._seg_id,
+                          batch=gb) as sp:
+                    if last:
+                        loss = ex.forward(ver_buf, x, self.data_fn(gb))
+                        jax.block_until_ready(loss)
+                        self.transport.send(self.dev, COORD, "loss",
+                                            (gb, float(loss)))
+                    elif fused:
+                        y, self._act_res = ex.forward_q(ver_buf, x,
+                                                        self._act_res)
+                        jax.block_until_ready(self._act_res)
+                    else:
+                        y = ex.forward(ver_buf, x)
+                        jax.block_until_ready(y)
+                    # the backward recomputes the forward from exactly
+                    # this (version buffer, input) pair — same residuals
+                    # the old vjp-closure path kept alive, without
+                    # storing them
+                    self._fwd_ctx[op.batch] = (ver_buf, x)
+                dt = sp.dt
                 if cap > 1.0:
                     time.sleep(dt * (cap - 1.0))
                     dt *= cap
@@ -719,26 +752,29 @@ class Worker(threading.Thread):
                 if last:
                     ct = None
                 else:
-                    ct = self._await(self._grads, op.batch)
+                    ct = self._await(self._grads, op.batch, waited)
                     if ct is None:
                         break
-                t0 = time.perf_counter()
-                ver_buf, x = self._fwd_ctx.pop(op.batch)
-                if fused and stage > 0:
-                    # quantize the outgoing cotangent inside the same
-                    # compiled call (stage 0 sends no grad — plain step)
-                    g_x, new_buf, self.mom_buf, self._grad_res = ex.step_q(
-                        ver_buf, self.stash.newest(), self.mom_buf, x, ct,
-                        self.data_fn(gb) if last else None, self._grad_res)
-                else:
-                    g_x, new_buf, self.mom_buf = ex.step(
-                        ver_buf, self.stash.newest(), self.mom_buf, x, ct,
-                        self.data_fn(gb) if last else None)
-                jax.block_until_ready(new_buf)
-                self.stash.push(max(gb + 1, self.stash.newest_v + 1),
-                                new_buf)
-                self.backwards_done += 1
-                dt = time.perf_counter() - t0
+                with Span(f"{self.span}.step", seg=self._seg_id,
+                          batch=gb) as sp:
+                    ver_buf, x = self._fwd_ctx.pop(op.batch)
+                    if fused and stage > 0:
+                        # quantize the outgoing cotangent inside the same
+                        # compiled call (stage 0 sends no grad — plain step)
+                        g_x, new_buf, self.mom_buf, self._grad_res = \
+                            ex.step_q(ver_buf, self.stash.newest(),
+                                      self.mom_buf, x, ct,
+                                      self.data_fn(gb) if last else None,
+                                      self._grad_res)
+                    else:
+                        g_x, new_buf, self.mom_buf = ex.step(
+                            ver_buf, self.stash.newest(), self.mom_buf, x,
+                            ct, self.data_fn(gb) if last else None)
+                    jax.block_until_ready(new_buf)
+                    self.stash.push(max(gb + 1, self.stash.newest_v + 1),
+                                    new_buf)
+                    self.backwards_done += 1
+                dt = sp.dt
                 if cap > 1.0:
                     time.sleep(dt * (cap - 1.0))
                     dt *= cap
@@ -774,8 +810,13 @@ class Worker(threading.Thread):
         # these) or enter recovery — either way the queue must be empty by
         # seg_done so fault-path behavior is deterministic
         self._ship_pending()
+        wait = waited["wait_s"]
+        # host_s: the rest of the segment's wall time — sends, data_fn,
+        # stash push and prune, overlap shipments, dispatch
+        host = time.perf_counter() - t_enter - busy - wait
         self.transport.send(self.dev, COORD, "seg_done",
-                            {"stage": stage, "busy": busy, "nb": nb,
+                            {"stage": stage, "nb": nb,
+                             "busy_s": busy, "wait_s": wait, "host_s": host,
                              "batch_times": sorted(batch_times.values()),
                              "seg_id": self._seg_id,
                              "ops_done": done_ops, "aborted":
@@ -1216,6 +1257,16 @@ class Coordinator:
         self._done: dict[int, dict] = {}
         self._committed = -1
         self.commit_times: dict[int, float] = {}
+        # counters of the ftp.* spans (LiveResult.stage_stats and
+        # .control_points; running totals in chain_status())
+        self.stage_stats: list = []
+        self.control_points: list = []
+        self._stage_totals: dict[int, dict] = {}
+        self._control_totals = {"points": 0, "drain_s": 0.0,
+                                "replicate_s": 0.0, "refill_s": 0.0}
+        self._boundary: Optional[dict] = None   # the control point under
+        #   way: drained (and replicated), its refill not yet committed
+        self._refill: Optional[Span] = None
         self._last_hb: dict[int, float] = {}
         self._ready_acks: dict[int, set] = {}    # refit version -> acked devs
         self._ready_missing: dict[int, list] = {}
@@ -1281,6 +1332,9 @@ class Coordinator:
                 "start_batch": int(self.cfg.start_batch)},
             "wire": self.transport.stats_snapshot(),
             "membership": self.membership(),
+            "stages": {d: dict(t)
+                       for d, t in list(self._stage_totals.items())},
+            "control": dict(self._control_totals),
         }
 
     def _send_all(self, worker_ids, kind, payload_fn):
@@ -1364,6 +1418,7 @@ class Coordinator:
                 self.stash_high_water[msg.src] = max(
                     self.stash_high_water.get(msg.src, 0),
                     msg.payload["stash_high_water"])
+                self._count_stage(msg.src, msg.payload)
         elif msg.kind == "hello":
             self._absorb_hello(msg)
         elif msg.kind == "cap_probe_ack":
@@ -1391,6 +1446,44 @@ class Coordinator:
                 if msg.payload >= rb:
                     self._request_spawn(dev)
                     del self._respawn[dev]
+
+    def _count_stage(self, dev: int, p: dict) -> None:
+        """Record one worker's ``seg_done`` counters and add them to its
+        running totals."""
+        self.stage_stats.append(
+            {"seg_id": p["seg_id"], "dev": int(dev),
+             "t_done": time.monotonic() - self._t0, "nb": p["nb"],
+             "busy_s": p["busy_s"], "wait_s": p["wait_s"],
+             "host_s": p["host_s"]})
+        tot = self._stage_totals.setdefault(
+            int(dev), {"busy_s": 0.0, "wait_s": 0.0, "host_s": 0.0,
+                       "batches": 0})
+        for k in ("busy_s", "wait_s", "host_s"):
+            tot[k] += p[k]
+        tot["batches"] += len(p["batch_times"])
+
+    def _end_refill(self, batch: int) -> None:
+        """The first commit after a control point: close its refill and
+        record it."""
+        cp = self._boundary
+        cp["refill_s"] = self._refill.close()
+        self._boundary = self._refill = None
+        self.control_points.append(
+            {"batch": cp["batch"], "t": self.commit_times[batch],
+             "drain_s": cp["drain_s"], "replicate_s": cp["replicate_s"],
+             "refill_s": cp["refill_s"]})
+        tot = self._control_totals
+        tot["points"] += 1
+        for k in ("drain_s", "replicate_s", "refill_s"):
+            tot[k] += cp[k]
+
+    def _drop_boundary(self, *open_spans) -> None:
+        """A failure or stall ends the control point under way unrecorded;
+        spans still open close where they are."""
+        for sp in (*open_spans, self._refill):
+            if sp is not None:
+                sp.close()
+        self._boundary = self._refill = None
 
     def _absorb_hello(self, msg) -> None:
         """Record a join/rejoin request. Epoch fencing happens HERE: a
@@ -1674,35 +1767,39 @@ class Coordinator:
         caller needs the receiving store complete on return (fleet sync,
         final collect): it drains even under ``overlap_replication`` —
         the shared ``ProtocolConfig.replication_mode`` decision."""
-        n = len(worker_ids)
-        mode = self.proto.replication_mode(seeding=full, barrier=barrier)
-        overlap = mode == "overlap"
-        self._send_all(worker_ids, "replicate",
-                       lambda i, dev: {"batch": batch, "chain": do_chain,
-                                       "global": do_global, "stage": i,
-                                       "chain_to": worker_ids[(i + 1) % n],
-                                       "full": full, "overlap": overlap})
-        # short ack window: a worker that died right at the segment boundary
-        # (its seg_done already sent) must not stall the control plane for
-        # segment_timeout — the NEXT segment's heartbeat monitor will catch
-        # it and run the §III-F path
-        got = self._collect({"replicated"}, n,
-                            timeout=max(1.0, 2 * self.proto.detect_timeout))
-        kind = ("chain+global" if do_chain and do_global
-                else "chain" if do_chain else "global")
-        tag = " (overlapped)" if overlap else ""
-        if got < n:
-            self._log(f"{kind} replication @batch {batch}{tag}: only "
-                      f"{got}/{n} acks — continuing, failure detection "
-                      f"will follow")
-        else:
-            self._log(f"{kind} replication @batch {batch}{tag}")
-        if do_global:
-            # per-sender FIFO puts every worker's global_put ahead of its
-            # "replicated" ack, so by now the store holds this round's
-            # snapshots (short-ack stragglers only make the floor
-            # conservative) — the right moment to commit durable state
-            self._durable_sync(part, worker_ids)
+        with Span("ftp.coord.replicate", self._boundary, "replicate_s",
+                  batch=batch):
+            n = len(worker_ids)
+            mode = self.proto.replication_mode(seeding=full, barrier=barrier)
+            overlap = mode == "overlap"
+            self._send_all(
+                worker_ids, "replicate",
+                lambda i, dev: {"batch": batch, "chain": do_chain,
+                                "global": do_global, "stage": i,
+                                "chain_to": worker_ids[(i + 1) % n],
+                                "full": full, "overlap": overlap})
+            # short ack window: a worker that died right at the segment
+            # boundary (its seg_done already sent) must not stall the
+            # control plane for segment_timeout — the NEXT segment's
+            # heartbeat monitor will catch it and run the §III-F path
+            got = self._collect(
+                {"replicated"}, n,
+                timeout=max(1.0, 2 * self.proto.detect_timeout))
+            kind = ("chain+global" if do_chain and do_global
+                    else "chain" if do_chain else "global")
+            tag = " (overlapped)" if overlap else ""
+            if got < n:
+                self._log(f"{kind} replication @batch {batch}{tag}: only "
+                          f"{got}/{n} acks — continuing, failure detection "
+                          f"will follow")
+            else:
+                self._log(f"{kind} replication @batch {batch}{tag}")
+            if do_global:
+                # per-sender FIFO puts every worker's global_put ahead of its
+                # "replicated" ack, so by now the store holds this round's
+                # snapshots (short-ack stragglers only make the floor
+                # conservative) — the right moment to commit durable state
+                self._durable_sync(part, worker_ids)
 
     def _durable_sync(self, part: PartitionResult, worker_ids: list) -> None:
         """Commit the durable control plane (run_dir runs only): fsync the
@@ -1858,8 +1955,12 @@ class Coordinator:
 
     def _run_segment(self, b0: int, nb: int, part: PartitionResult,
                      worker_ids: list):
-        """Returns (ok, stats | suspects, committed)."""
+        """Returns (ok, stats | suspects, committed). The segment's first
+        commit ends the refill of the control point before it; its own
+        drain runs from its last commit to its last seg_done."""
         n = len(worker_ids)
+        last = b0 + nb - 1
+        drain = None
         self._seg_counter += 1
         self._cur_seg = self._seg_counter
         self._done = {}
@@ -1878,36 +1979,47 @@ class Coordinator:
                 # a wedge without heartbeat loss (e.g. a dropped act/grad —
                 # there is no data-plane retransmission): hand it to the
                 # stall/restart path rather than crashing the run
+                self._drop_boundary(drain)
                 return False, {"suspects": []}, self._committed
             msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
+                if msg.kind == "commit" and msg.payload >= b0:
+                    if self._refill is not None:
+                        self._end_refill(msg.payload)
+                    if drain is None and msg.payload >= last:
+                        drain = Span("ftp.coord.drain", batch=last).open()
             suspects = [dev for dev in worker_ids
                         if dev not in self._done
                         and now - self._last_hb[dev]
                         > self.proto.detect_timeout]
             if suspects:
+                self._drop_boundary(drain)
                 return False, {"suspects": suspects}, self._committed
+        self._boundary = {"batch": b0 + nb, "replicate_s": 0.0,
+                          "drain_s": 0.0 if drain is None else drain.close()}
         return True, dict(self._done), self._committed
 
     def _probe(self, worker_ids: list) -> dict:
         """§III-F: on timer expiry the central node probes every worker."""
-        for dev in worker_ids:
-            if dev != 0:
-                self.transport.send(COORD, dev, "probe", {})
-        responses: dict[int, Optional[str]] = {dev: None for dev in worker_ids
-                                               if dev != 0}
-        deadline = time.monotonic() + max(10 * self.proto.probe_rtt, 0.3)
-        while time.monotonic() < deadline:
-            msg = self._recv()
-            if msg is None:
-                continue
-            self._absorb(msg)
-            if msg.kind in ("probe_ack", "hb") and msg.src in responses:
-                responses[msg.src] = "ok"
-            if all(r is not None for r in responses.values()):
-                break
-        return responses
+        with Span("ftp.coord.probe"):
+            for dev in worker_ids:
+                if dev != 0:
+                    self.transport.send(COORD, dev, "probe", {})
+            responses: dict[int, Optional[str]] = {
+                dev: None for dev in worker_ids if dev != 0}
+            deadline = time.monotonic() + max(10 * self.proto.probe_rtt,
+                                              0.3)
+            while time.monotonic() < deadline:
+                msg = self._recv()
+                if msg is None:
+                    continue
+                self._absorb(msg)
+                if msg.kind in ("probe_ack", "hb") and msg.src in responses:
+                    responses[msg.src] = "ok"
+                if all(r is not None for r in responses.values()):
+                    break
+            return responses
 
     def _abort_segment(self, worker_ids: list, dead: set):
         """Drain the wedged pipeline: wait until every survivor has posted
@@ -2119,7 +2231,9 @@ class Coordinator:
             shipped_gens=dict(self.shipped_gens),
             stage_devices={
                 dev: sorted(d.id for d in w.stash.newest().devices())
-                for dev, w in self.workers.items() if w.stash is not None})
+                for dev, w in self.workers.items() if w.stash is not None},
+            stage_stats=list(self.stage_stats),
+            control_points=list(self.control_points))
 
     def _run_protocol(self, est, part, partitions, worker_ids, profile,
                       state):
@@ -2136,6 +2250,11 @@ class Coordinator:
             if self._stop_requested.is_set():
                 self._log(f"stop requested @batch {b0}")
                 break
+            if self._boundary is not None:
+                # the control work is done: the refill runs from here, the
+                # next segment's planning and messages included, to its
+                # first commit
+                self._refill = Span("ftp.coord.refill", batch=b0).open()
             pts = [p for p in proto.control_points(B) if p > b0]
             nxt = pts[0] if pts else B
             ok, info, committed = self._run_segment(b0, nxt - b0, part,
@@ -2195,7 +2314,7 @@ class Coordinator:
                 # median per-batch time: robust to first-call tracing
                 # and thread-scheduling spikes
                 bt = stats.get("batch_times") or [
-                    stats["busy"] / max(stats["nb"], 1)]
+                    stats["busy_s"] / max(stats["nb"], 1)]
                 return float(np.median(bt))
 
             a0, e0 = part.ranges[0]
@@ -2343,35 +2462,39 @@ class Coordinator:
         ``(worker_ids, part, est, restart_batch)``. A FURTHER failure
         during the recovery redistribution recurses (each round removes at
         least one worker, so depth is bounded by the cluster size)."""
-        self._log(f"failure detected: devs {sorted(dead)}; probing done")
-        for dev in dead:      # ensure a non-responder is truly gone
-            self._fence_worker(dev)
-        survivors = [d for d in worker_ids if d not in dead]
-        if len(survivors) < max(1, self.cfg.min_workers):
-            # whole-chain loss: recovering below the floor would leave a
-            # straggler replica, so the chain collapses as a unit — the
-            # fleet degrades to M-1 contributors and re-admits a fresh
-            # chain at a later aggregation round (runtime/fleet.py)
-            self._log(f"chain collapsed: {len(survivors)} survivors < "
-                      f"min_workers={self.cfg.min_workers}")
-            if self.aggregator is not None:
-                self.aggregator.chain_dead(self.chain_id)
-            raise ChainCollapsedError(self.chain_id, survivors,
-                                      sorted(dead))
-        for dev in worker_ids:      # release anyone mid-refit fetching from
-            if dev not in dead:     # the corpse — abandon, don't backstop
-                self.transport.send(COORD, dev, "refit_abort", {})
-        self._abort_segment(worker_ids, set(dead))
-        failed_pos = [worker_ids.index(d) for d in dead]
-        dec = protocol.plan_failure_recovery(
-            part, worker_ids, failed_pos, est, profile,
-            self.bandwidth, self.proto.comm_factor,
-            static=self.cfg.static_partition)
-        restart = self._committed + 1
-        state.reset_after_recovery(restart)
-        shortfall = self._redistribute(dec.partition, dec.plans,
-                                       dec.worker_ids, version=restart,
-                                       kind="recover")
+        self._drop_boundary()     # no control point spans a recovery
+        with Span("ftp.coord.recover"):
+            self._log(f"failure detected: devs {sorted(dead)}; probing done")
+            for dev in dead:      # ensure a non-responder is truly gone
+                self._fence_worker(dev)
+            survivors = [d for d in worker_ids if d not in dead]
+            if len(survivors) < max(1, self.cfg.min_workers):
+                # whole-chain loss: recovering below the floor would leave a
+                # straggler replica, so the chain collapses as a unit — the
+                # fleet degrades to M-1 contributors and re-admits a fresh
+                # chain at a later aggregation round (runtime/fleet.py)
+                self._log(f"chain collapsed: {len(survivors)} survivors < "
+                          f"min_workers={self.cfg.min_workers}")
+                if self.aggregator is not None:
+                    self.aggregator.chain_dead(self.chain_id)
+                raise ChainCollapsedError(self.chain_id, survivors,
+                                          sorted(dead))
+            # release anyone mid-refit fetching from the corpse — abandon,
+            # don't backstop
+            for dev in worker_ids:
+                if dev not in dead:
+                    self.transport.send(COORD, dev, "refit_abort", {})
+            self._abort_segment(worker_ids, set(dead))
+            failed_pos = [worker_ids.index(d) for d in dead]
+            dec = protocol.plan_failure_recovery(
+                part, worker_ids, failed_pos, est, profile,
+                self.bandwidth, self.proto.comm_factor,
+                static=self.cfg.static_partition)
+            restart = self._committed + 1
+            state.reset_after_recovery(restart)
+            shortfall = self._redistribute(dec.partition, dec.plans,
+                                           dec.worker_ids, version=restart,
+                                           kind="recover")
         worker_ids, part, est = dec.worker_ids, dec.partition, dec.est
         if shortfall:
             if depth + 1 >= self.cfg.num_workers:

@@ -251,11 +251,13 @@ class TransportBase(abc.ABC):
     def stats_snapshot(self) -> dict:
         """``self.stats`` plus the link shaper's counters (``shaped``,
         ``netem_blocked``, per-link breakdowns) when a NetemSpec is
-        active — the view result reports carry."""
+        active — the view result reports carry. A copy, nested counters
+        included: a caller that edits it leaves the live counters alone."""
         snap = dict(self.stats)
         if getattr(self, "netem", None) is not None:
             snap.update(self.netem.stats)
-        return snap
+        return {k: dict(v) if isinstance(v, dict) else v
+                for k, v in snap.items()}
 
     # -------------------- shared reliable-data layer --------------------
 

@@ -39,9 +39,10 @@ MESSAGES = [
     ("hb", {"t": 123.25}),
     ("segment", {"stage": 1, "n": 3, "b0": 10, "nb": 5,
                  "stage_devs": [0, 1, 2], "seg_id": 4}),
-    ("seg_done", {"stage": 1, "busy": 0.25, "nb": 5,
-                  "batch_times": [0.01, 0.02], "seg_id": 4, "ops_done": 10,
-                  "aborted": False, "stash_high_water": 4}),
+    ("seg_done", {"stage": 1, "nb": 5, "busy_s": 0.25, "wait_s": 0.125,
+                  "host_s": 0.0625, "batch_times": [0.01, 0.02],
+                  "seg_id": 4, "ops_done": 10, "aborted": False,
+                  "shipped_gen": -1, "stash_high_water": 4}),
     ("replicate", {"batch": 10, "chain": True, "global": False, "stage": 1,
                    "chain_to": 2}),
     ("replicated", {"stage": 1}),

@@ -214,7 +214,8 @@ def test_run_status_and_stop(tmp_path):
     assert run.status()["state"] == "created"
     run.start()
     deadline = time.monotonic() + 60
-    while run.status()["batches_done"] < 2:     # prove it actually trains
+    while run.status()["chains"].get(0, {}).get(
+            "progress", {}).get("batches_done", 0) < 2:   # it trains
         assert time.monotonic() < deadline
         time.sleep(0.01)
     run.stop()                              # wind down at a batch boundary

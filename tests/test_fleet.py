@@ -236,8 +236,7 @@ def test_status_nested_schema_before_start():
     assert s["state"] == "created"
     assert s["fleet"]["chains"] == 2
     assert s["chains"] == {}             # nothing launched yet
-    # deprecated flat aliases survive one release
-    assert s["batches_done"] == 0
+    assert s["fleet"]["live"] == [] and s["fleet"]["rounds"] == 0
 
 
 def test_fleet_rejects_resume_and_addr_of():
@@ -289,7 +288,8 @@ def test_fleet_status_nested_schema_live():
     assert set(s["chains"]) <= {0, 1}
     for st_ in s["chains"].values():
         assert {"progress", "wire", "membership"} <= set(st_)
-    assert s["batches_done"] == 10       # deprecated alias still present
+    assert max(c["progress"]["batches_done"]
+               for c in s["chains"].values()) == 10
 
 
 @pytest.mark.live

@@ -362,7 +362,7 @@ class TestPerKindStats:
         run = start_run(cfg)
         run.wait()
         status = run.status()
-        wire = status["wire"]
+        wire = status["chains"][0]["wire"]
         assert wire["bytes"] > 0
         assert set(wire["kind_bytes"]) \
             == {"act", "grad", "replica", "replica_ov", "control"}
@@ -370,7 +370,7 @@ class TestPerKindStats:
         assert wire["kind_msgs"]["control"] > 0
         # mutating the copy must not touch the transport's counters
         wire["kind_bytes"]["act"] = -1
-        assert run.status()["wire"]["kind_bytes"]["act"] > 0
+        assert run.status()["chains"][0]["wire"]["kind_bytes"]["act"] > 0
 
 
 def test_run_tcp_training_refuses_when_this_process_holds_a_chip(
