@@ -16,7 +16,7 @@ Conventions (0-indexed batches, n = number of stages):
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator
+from typing import Iterator, Optional
 
 
 def version_for_batch(b: int, n: int) -> int:
@@ -67,17 +67,35 @@ def stage_schedule(stage: int, n: int, num_batches: int) -> Iterator[Op]:
     Startup: (n - stage) forwards; then alternate bwd/fwd; drain with
     remaining backwards. Versions follow vertical sync.
     """
+    for idx in range(2 * num_batches):
+        yield stage_op(stage, n, num_batches, idx)
+
+
+def stage_op(stage: int, n: int, num_batches: int, idx: int) -> Op:
+    """Op ``idx`` (of ``2 * num_batches``) of ``stage_schedule``, in O(1):
+    a runtime walks a long segment without building its schedule."""
     warm = min(warmup_forwards(stage, n), num_batches)
-    next_f, next_b = 0, 0
-    for _ in range(warm):
-        yield Op("fwd", next_f, version_for_batch(next_f, n))
-        next_f += 1
-    while next_b < num_batches:
-        yield Op("bwd", next_b, version_for_batch(next_b, n))
-        next_b += 1
-        if next_f < num_batches:
-            yield Op("fwd", next_f, version_for_batch(next_f, n))
-            next_f += 1
+    pairs = num_batches - warm          # bwd/fwd pairs after the startup
+    if idx < warm:
+        kind, b = "fwd", idx
+    elif idx < warm + 2 * pairs:
+        j, odd = divmod(idx - warm, 2)
+        kind, b = ("fwd", warm + j) if odd else ("bwd", j)
+    else:                               # the drain: backwards only
+        kind, b = "bwd", idx - warm - pairs
+    return Op(kind, b, version_for_batch(b, n))
+
+
+def next_forward(stage: int, n: int, num_batches: int,
+                 idx: int) -> Optional[int]:
+    """Batch of the first forward at or after op ``idx`` of
+    ``stage_schedule``, or None when only backwards remain."""
+    warm = min(warmup_forwards(stage, n), num_batches)
+    if idx < warm:
+        return idx
+    if idx < 2 * num_batches - warm:
+        return warm + (idx - warm) // 2
+    return None
 
 
 def validate_schedule(ops: list[Op], stage: int, n: int) -> None:

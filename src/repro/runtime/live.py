@@ -26,9 +26,10 @@ via ``core/capacity.py``, DP from ``core/partition.py``, fetches from
 heartbeat timeout -> probe -> classify via ``core/fault.py`` -> renumber ->
 recovery partition -> weight redistribution -> reset ids -> resume). The
 simulator (``runtime/simulator.py``) predicts this runtime's decisions on a
-virtual clock; both drain the pipeline at the same
-``ProtocolConfig.control_points`` (the batch-boundary approximation the
-simulator documents is this runtime's actual execution strategy).
+virtual clock; both drain the pipeline where ``ProtocolConfig.drains`` says
+(the batch-boundary approximation the simulator documents is this
+runtime's actual execution strategy) and replicate inside the running
+segment at every other replication point.
 
 In-process notes: workers are threads sharing one JAX runtime, so
 "devices" here exercise the PROTOCOL (heterogeneity enters via measured or
@@ -39,6 +40,7 @@ only activations/gradients/weights travel the transport.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import threading
 import time
@@ -67,6 +69,8 @@ from repro.runtime.transport import (FaultSpec, Heartbeat, Transport,
 from repro.runtime.workload import LayerChain
 
 COORD = -1          # coordinator control-plane node id on the transport
+# a worker's counters of its batches, in seg_done and in-segment reports
+_COUNTERS = ("nb", "busy_s", "wait_s", "host_s")
 
 
 class WorkerError(RuntimeError):
@@ -255,12 +259,13 @@ class LiveConfig:
     #   replication round is not free)
     # ---- overlap-everything scheduler (ROADMAP direction 5) -------------
     overlap_replication: bool = False   # §III-E replication (and §III-D
-    #   admission capacity probes) leave the control point as a snapshot
-    #   + immediate ack; the replica bytes ship DURING the next segment's
-    #   compute instead of inside the drain. Seeding rounds (batch 0,
-    #   post-admission re-seed) and barrier rounds (fleet sync, final
-    #   collect) always drain. Off = drain mode, the control arm the WAN
-    #   bench compares against (docs/protocol.md §10)
+    #   admission capacity probes) leave the round as a snapshot +
+    #   immediate ack; the replica bytes ship DURING the ops that follow
+    #   (the next segment's, after a drained point) instead of before the
+    #   worker's next op. Seeding rounds (batch 0, post-admission re-seed)
+    #   and barrier rounds (fleet sync, final collect) always drain. Off =
+    #   drain mode, the control arm the WAN bench compares against
+    #   (docs/protocol.md §10, §11)
     repl_delta: str = "counters"        # §III-E delta-skip detector:
     #   "counters" consults the StageExecutor's O(1) per-layer change
     #   counters (a layer whose counter matches the last ship is skipped
@@ -326,6 +331,13 @@ class LiveResult:
     #   segment's last commit to its last seg_done, the replication, and
     #   the end of the control work to the first commit of the segment
     #   from ``batch`` (at ``t``, the coordinator's clock)
+    replications_inline: int = 0
+    #   replication rounds run inside a running segment (every worker
+    #   passed them), no drain around them
+    drains: int = 0
+    #   control points at which the pipeline drained between two segments
+    #   (a re-partition, a fleet barrier, a stop or an admission), and the
+    #   final collect
 
     @property
     def final_partition(self) -> tuple:
@@ -426,6 +438,13 @@ class Worker(threading.Thread):
         self._act_res = None
         self._grad_res = None
         self._fetch_res: dict[int, dict] = {}
+        # a boundary on demand (docs/protocol.md §11): the segment the
+        # coordinator asked stage 0 to cut, stage 0's chosen (segment id,
+        # end), and the cut points stage 0 announced to the other stages,
+        # by segment id
+        self._cut_req = -1
+        self._cut_at = (-1, -1)
+        self._cuts: dict[int, int] = {}
         # pre-refit snapshot: peers' redistribution plans reference the OLD
         # partition, so fetches must be served from it even after this
         # worker has already committed its own new slice
@@ -591,6 +610,8 @@ class Worker(threading.Thread):
                                     {"status": "ok"})
             elif k == "cap_probe":
                 self._do_cap_probe(msg.payload)
+            elif k == "cut":
+                self._note_cut(msg.payload)
             elif k == "admit":
                 # admission confirmed; adopt the coordinator's wire policy
                 # (the repart that follows carries the slice assignment)
@@ -626,6 +647,8 @@ class Worker(threading.Thread):
             self._fetch_res[msg.payload["req_id"]] = msg.payload["layers"]
         elif k == "cap_probe":
             self._do_cap_probe(msg.payload)
+        elif k == "cut":
+            self._note_cut(msg.payload)
         elif k == "abort":
             self.abort_event.set()
         elif k == "refit_abort":
@@ -635,16 +658,25 @@ class Worker(threading.Thread):
         elif k == "stop":
             self.stop_event.set()
 
+    def _note_cut(self, payload: dict) -> None:
+        """Record a ``cut``: the coordinator's request (to stage 0) or
+        stage 0's chosen end (``at``) of a segment."""
+        if "at" in payload:
+            self._cuts[payload["seg_id"]] = payload["at"]
+        else:
+            self._cut_req = payload["seg_id"]
+
     def _await(self, store: dict, key: int, waited: dict):
-        """The act or grad of batch ``key``; None once the run stops or
-        the segment aborts. Time spent waiting for it is a ``wait`` span,
-        added to ``waited["wait_s"]``."""
+        """The act or grad of batch ``key``; None once the run stops, the
+        segment aborts or stage 0 cuts it. Time spent waiting for it is a
+        ``wait`` span, added to ``waited["wait_s"]``."""
         if key not in store:
             with Span(f"{self.span}.wait", waited, "wait_s",
                       seg=self._seg_id):
                 while key not in store:
                     if self.stop_event.is_set() \
-                            or self.abort_event.is_set():
+                            or self.abort_event.is_set() \
+                            or self._seg_id in self._cuts:
                         return None
                     msg = self.transport.recv(self.dev,
                                               timeout=self.cfg.poll)
@@ -679,6 +711,8 @@ class Worker(threading.Thread):
                 (self._acts if kind == "act" else self._grads)[b] = x
             elif sid > self._seg_id:
                 self._future[(sid, kind, b)] = x   # still ahead of us
+        self._cuts = {sid: at for sid, at in self._cuts.items()
+                      if sid >= self._seg_id}
         self._fwd_ctx.clear()
         self._pre_refit = {}          # redistribution is over once we train
         last = stage == n - 1
@@ -690,26 +724,48 @@ class Worker(threading.Thread):
         policy = getattr(self.transport, "policy", None)
         fused = (policy is not None
                  and policy.tier_for("act") == "int8-fused")
-
-        ops = list(sched.stage_schedule(stage, n, nb))
-        # for retention pruning: next fwd batch at-or-after each op index
-        next_fwd = [None] * (len(ops) + 1)
-        for idx in range(len(ops) - 1, -1, -1):
-            next_fwd[idx] = (b0 + ops[idx].batch if ops[idx].kind == "fwd"
-                             else next_fwd[idx + 1])
+        # in-segment replication rounds (docs/protocol.md §11): point p ->
+        # the spec ``_do_replicate`` takes, run right after this stage's
+        # step of batch p - 1, when the stash's newest is version p
+        rep = spec.get("replicate") or {}
+        points = {p: {"batch": p, "chain": c, "global": g, "stage": stage,
+                      "chain_to": rep["chain_to"], "full": False,
+                      "overlap": rep["overlap"]}
+                  for p, c, g in rep.get("points", ())}
 
         # fwd+step seconds per batch (Eq. 1's input), their sum over the
         # segment, and the seconds spent waiting for an act or grad
         batch_times: dict[int, float] = {}
-        busy, done_ops = 0.0, 0
+        busy, done_ops, fwds = 0.0, 0, 0
         waited = {"wait_s": 0.0}
-        for idx, op in enumerate(ops):
+        # the counters as of the last in-segment report: (clock, busy,
+        # wait, first batch not reported yet)
+        mark = (t_enter, 0.0, 0.0, 0)
+        # the 1F1B ops are computed one at a time (``stage_op``): a
+        # segment may run to the horizon, 20,000 batches away
+        idx = 0
+        while idx < 2 * nb:
+            if n == 1:
+                # a lone stage awaits no act or grad: read the inbox
+                # between its ops, so a cut, a probe or an abort is seen
+                while (msg := self.transport.recv(self.dev,
+                                                  timeout=0)) is not None:
+                    self._dispatch(msg)
             if self.stop_event.is_set() or self.abort_event.is_set():
                 break
+            end = self._cut_to(stage, b0 + nb, b0 + fwds, points, devs)
+            if end is not None:
+                # the 1F1B schedule of fewer batches is a prefix of this
+                # one up to the forward of batch ``end``, which no stage
+                # has run: carry on from the same op
+                nb = end - b0
+                points = {p: s for p, s in points.items() if p < end}
+                continue
             # overlap scheduler: interleave ONE deferred replica shipment
             # per op, so the §III-E bytes ride this segment's compute
             # instead of a control-point drain
             self._ship_pending(limit=1)
+            op = sched.stage_op(stage, n, nb, idx)
             gb = b0 + op.batch
             if op.kind == "fwd":
                 if stage == 0:
@@ -717,6 +773,8 @@ class Worker(threading.Thread):
                 else:
                     x = self._await(self._acts, op.batch, waited)
                     if x is None:
+                        if self._seg_id in self._cuts:
+                            continue       # cut while waiting: re-plan
                         break
                 ver = sched.version_for_batch(gb, n)
                 ver_buf = self.stash.get(ver)
@@ -745,6 +803,7 @@ class Worker(threading.Thread):
                     dt *= cap
                 busy += dt
                 batch_times[op.batch] = batch_times.get(op.batch, 0.0) + dt
+                fwds += 1
                 if not last:
                     self.transport.send(self.dev, devs[stage + 1], "act",
                                         (self._seg_id, op.batch, y))
@@ -754,6 +813,8 @@ class Worker(threading.Thread):
                 else:
                     ct = self._await(self._grads, op.batch, waited)
                     if ct is None:
+                        if self._seg_id in self._cuts:
+                            continue       # cut while waiting: re-plan
                         break
                 with Span(f"{self.span}.step", seg=self._seg_id,
                           batch=gb) as sp:
@@ -800,10 +861,15 @@ class Worker(threading.Thread):
                 # retention target: the next forward here, or — once this
                 # segment has none left — the NEXT segment's first batch,
                 # so vertical sync survives the control-point drain
-                nf = next_fwd[idx + 1]
+                nf = sched.next_forward(stage, n, nb, idx + 1)
                 self.stash.prune(sched.version_for_batch(
-                    b0 + nb if nf is None else nf, n))
+                    b0 + (nb if nf is None else nf), n))
+                if gb + 1 in points:
+                    mark = self._replicate_inline(
+                        points.pop(gb + 1), mark, busy, waited["wait_s"],
+                        batch_times, op.batch + 1)
             done_ops += 1
+            idx += 1
         self.stash.prune(sched.version_for_batch(b0 + nb, n))
         # flush whatever overlap shipments the segment's ops did not cover:
         # the control point that follows may replicate again (superseding
@@ -818,11 +884,61 @@ class Worker(threading.Thread):
                             {"stage": stage, "nb": nb,
                              "busy_s": busy, "wait_s": wait, "host_s": host,
                              "batch_times": sorted(batch_times.values()),
+                             # Eq. 1's input since the last in-segment
+                             # report (the whole segment where none was)
+                             "tail_times": sorted(
+                                 t for b, t in batch_times.items()
+                                 if b >= mark[3]),
                              "seg_id": self._seg_id,
                              "ops_done": done_ops, "aborted":
-                             done_ops < len(ops),
+                             done_ops < 2 * nb,
                              "shipped_gen": self._shipped_gen,
                              "stash_high_water": self.stash.high_water})
+
+    def _cut_to(self, stage: int, end: int, next_fwd: int, points: dict,
+                devs: list) -> Optional[int]:
+        """The new end of the running segment where a cut shortens it,
+        else None. Stage 0 answers the coordinator's request: the first
+        in-segment point after its next unforwarded batch ``next_fwd``,
+        announced to the other stages and the coordinator (again on a
+        repeated request: a cut can be lost on the wire). No stage can
+        forward a batch stage 0 has not, so none has run the forward of
+        batch ``next_fwd``, nor the step of batch ``at - 1`` after which
+        the round at ``at`` would run. The other stages take stage 0's
+        word."""
+        if stage == 0:
+            if self._cut_req != self._seg_id:
+                return None
+            self._cut_req = -1
+            if self._cut_at[0] != self._seg_id:
+                self._cut_at = (self._seg_id, min(
+                    [p for p in points if p > next_fwd] + [end]))
+            at = self._cut_at[1]
+            for d in [*devs[1:], COORD]:
+                self.transport.send(self.dev, d, "cut",
+                                    {"seg_id": self._seg_id, "at": at})
+        else:
+            at = self._cuts.pop(self._seg_id, None)
+        return at if at is not None and at < end else None
+
+    def _replicate_inline(self, spec: dict, mark: tuple, busy: float,
+                          wait: float, batch_times: dict,
+                          upto: int) -> tuple:
+        """An in-segment replication round. Its ack carries this stage's
+        counters since the previous report (batches ``mark[3]`` to
+        ``upto - 1``), so the coordinator samples Eq. 1 at every
+        replication point; the round's own time falls in the next
+        report's host seconds. Returns the new mark."""
+        now = time.perf_counter()
+        t, busy0, wait0, first = mark
+        times = sorted(batch_times[b] for b in range(first, upto))
+        report = {"seg_id": self._seg_id, "nb": len(times),
+                  "busy_s": busy - busy0, "wait_s": wait - wait0,
+                  "host_s": (now - t) - (busy - busy0) - (wait - wait0),
+                  "batch_times": times}
+        with Span(f"{self.span}.replicate", batch=spec["batch"]):
+            self._do_replicate(dict(spec, report=report))
+        return (now, busy, wait, upto)
 
     # --------------------------- control plane ---------------------------
 
@@ -989,9 +1105,12 @@ class Worker(threading.Thread):
                     commit()
             self._ship_gen = spec["batch"]
             self._shipped_gen = max(self._shipped_gen, spec["batch"])
+        # an in-segment round's ack carries the stage's counters since
+        # its previous report (``_replicate_inline``)
         self.transport.send(self.dev, COORD, "replicated",
                             {"stage": spec["stage"], "overlap": overlap,
-                             "gen": spec["batch"]})
+                             "gen": spec["batch"],
+                             **spec.get("report", {})})
 
     def _store_chain(self, payload: dict):
         self.replicas.put_many(payload["batch"], payload["layers"],
@@ -1263,7 +1382,16 @@ class Coordinator:
         self.control_points: list = []
         self._stage_totals: dict[int, dict] = {}
         self._control_totals = {"points": 0, "drain_s": 0.0,
-                                "replicate_s": 0.0, "refill_s": 0.0}
+                                "replicate_s": 0.0, "refill_s": 0.0,
+                                "replications_inline": 0, "drains": 0}
+        # the running segment's in-segment rounds: acks by batch, the
+        # newest round each worker has passed, the counters each worker
+        # reported in them, and where the segment starts and ends (stage
+        # 0 may cut it short)
+        self._inline_acks: dict[int, dict] = {}
+        self._passed: dict[int, float] = {}
+        self._reported: dict[int, dict] = {}
+        self._seg_b0 = self._seg_end = 0
         self._boundary: Optional[dict] = None   # the control point under
         #   way: drained (and replicated), its refill not yet committed
         self._refill: Optional[Span] = None
@@ -1360,8 +1488,9 @@ class Coordinator:
         return self.transport.recv(COORD, timeout=self.cfg.poll)
 
     def _collect(self, kinds: set, expect: int, timeout: float,
-                 on_msg=None) -> int:
-        """Drain COORD inbox until `expect` messages of `kinds` arrived."""
+                 on_msg=None, match=None) -> int:
+        """Drain COORD inbox until `expect` messages of `kinds` (for which
+        ``match``, if given, holds) arrived."""
         got = 0
         deadline = time.monotonic() + timeout
         while got < expect and time.monotonic() < deadline:
@@ -1369,7 +1498,7 @@ class Coordinator:
             if msg is None:
                 continue
             self._absorb(msg)
-            if msg.kind in kinds:
+            if msg.kind in kinds and (match is None or match(msg)):
                 got += 1
             if on_msg is not None:
                 on_msg(msg)
@@ -1408,17 +1537,46 @@ class Coordinator:
                                       msg.payload.get("same", {}))
         elif msg.kind == "hb":
             self._last_hb[msg.src] = time.monotonic()
+        elif msg.kind == "replicated":
+            p = msg.payload
+            if p.get("seg_id") == self._cur_seg:
+                # an in-segment round: its ack carries the worker's
+                # counters since its previous report
+                self._inline_acks.setdefault(p["gen"], {})[msg.src] = p
+                self._passed[msg.src] = max(self._passed.get(msg.src, -1),
+                                            p["gen"])
+                rep = self._reported.setdefault(msg.src, {})
+                for k in _COUNTERS:
+                    rep[k] = rep.get(k, 0) + p[k]
+                rep["batches"] = rep.get("batches", 0) + len(p["batch_times"])
+                self._count_stage(msg.src, p, len(p["batch_times"]))
+        elif msg.kind == "cut":
+            if msg.payload.get("seg_id") == self._cur_seg:
+                self._seg_end = min(self._seg_end, msg.payload["at"])
         elif msg.kind == "seg_done":
             sg = msg.payload.get("shipped_gen", -1)
             if sg >= 0:
                 self.shipped_gens[msg.src] = max(
                     self.shipped_gens.get(msg.src, -1), sg)
             if msg.payload.get("seg_id") == self._cur_seg:
-                self._done[msg.src] = msg.payload
+                p = msg.payload
+                self._done[msg.src] = p
+                # per-sender FIFO: the worker has run every round of its
+                # segment, whose end its nb also says after a cut
+                self._passed[msg.src] = math.inf
+                if not p["aborted"]:
+                    self._seg_end = min(self._seg_end,
+                                        self._seg_b0 + p["nb"])
                 self.stash_high_water[msg.src] = max(
                     self.stash_high_water.get(msg.src, 0),
-                    msg.payload["stash_high_water"])
-                self._count_stage(msg.src, msg.payload)
+                    p["stash_high_water"])
+                # the segment's whole counters, less what its in-segment
+                # reports already counted
+                rep = self._reported.pop(msg.src, {})
+                rest = {k: p[k] - rep.get(k, 0) for k in _COUNTERS}
+                self._count_stage(msg.src, dict(rest, seg_id=p["seg_id"]),
+                                  len(p["batch_times"])
+                                  - rep.get("batches", 0))
         elif msg.kind == "hello":
             self._absorb_hello(msg)
         elif msg.kind == "cap_probe_ack":
@@ -1447,8 +1605,9 @@ class Coordinator:
                     self._request_spawn(dev)
                     del self._respawn[dev]
 
-    def _count_stage(self, dev: int, p: dict) -> None:
-        """Record one worker's ``seg_done`` counters and add them to its
+    def _count_stage(self, dev: int, p: dict, batches: int) -> None:
+        """Record one worker's counters (an in-segment round's report, or
+        the rest of its segment at ``seg_done``) and add them to its
         running totals."""
         self.stage_stats.append(
             {"seg_id": p["seg_id"], "dev": int(dev),
@@ -1460,7 +1619,7 @@ class Coordinator:
                        "batches": 0})
         for k in ("busy_s", "wait_s", "host_s"):
             tot[k] += p[k]
-        tot["batches"] += len(p["batch_times"])
+        tot["batches"] += batches
 
     def _end_refill(self, batch: int) -> None:
         """The first commit after a control point: close its refill and
@@ -1784,9 +1943,9 @@ class Coordinator:
             # heartbeat monitor will catch it and run the §III-F path
             got = self._collect(
                 {"replicated"}, n,
-                timeout=max(1.0, 2 * self.proto.detect_timeout))
-            kind = ("chain+global" if do_chain and do_global
-                    else "chain" if do_chain else "global")
+                timeout=max(1.0, 2 * self.proto.detect_timeout),
+                match=lambda m: "seg_id" not in m.payload)
+            kind = protocol.replication_kind(do_chain, do_global)
             tag = " (overlapped)" if overlap else ""
             if got < n:
                 self._log(f"{kind} replication @batch {batch}{tag}: only "
@@ -1953,25 +2112,49 @@ class Coordinator:
             self._log(f"fleet mean installed @batch {b0}")
         return pending
 
+    def _boundary_wanted(self, worker_ids: list) -> bool:
+        """A stop, or a device waiting to be admitted, needs a drained
+        boundary: the running segment is then cut at its next control
+        point (docs/protocol.md §11)."""
+        if self._stop_requested.is_set():
+            return True
+        return any(d not in worker_ids for d in (
+            *self._spawn_queue, *self._pending_joins, *self._join_deadline))
+
     def _run_segment(self, b0: int, nb: int, part: PartitionResult,
-                     worker_ids: list):
-        """Returns (ok, stats | suspects, committed). The segment's first
-        commit ends the refill of the control point before it; its own
-        drain runs from its last commit to its last seg_done."""
+                     worker_ids: list, on_round=None):
+        """Returns (ok, stats | suspects, end): the segment runs from
+        ``b0`` to ``end``, which is ``b0 + nb`` unless a cut brought it
+        forward. Every replication point strictly inside it is an
+        in-segment round: the workers replicate at their own batch
+        boundary, and ``on_round(batch, acks)`` takes the counters of a
+        round every worker acked. The segment's first commit ends the
+        refill of the control point before it; its own drain runs from
+        its last commit to its last seg_done."""
         n = len(worker_ids)
-        last = b0 + nb - 1
         drain = None
         self._seg_counter += 1
         self._cur_seg = self._seg_counter
+        self._seg_b0, self._seg_end = b0, b0 + nb
         self._done = {}
+        self._inline_acks = {}
+        self._passed = {}
+        self._reported = {}
         self._committed = b0 - 1
         self._last_hb = {dev: time.monotonic() for dev in worker_ids}
+        rounds = self.proto.inline_points(b0, b0 + nb)
+        overlap = self.proto.replication_mode() == "overlap"
         addrs = self._addrs_payload(worker_ids)
         self._send_all(
             worker_ids, "segment",
             lambda i, dev: {"stage": i, "n": n, "b0": b0, "nb": nb,
                             "stage_devs": list(worker_ids),
-                            "seg_id": self._cur_seg, "addrs": addrs})
+                            "seg_id": self._cur_seg, "addrs": addrs,
+                            "replicate": {
+                                "points": rounds, "overlap": overlap,
+                                "chain_to": worker_ids[(i + 1) % n]}})
+        r = 0                              # r: the next round to close
+        next_cut = 0.0                     # when to (re)send a cut
         deadline = time.monotonic() + self.cfg.segment_timeout
         while len(self._done) < n:
             now = time.monotonic()
@@ -1980,25 +2163,87 @@ class Coordinator:
                 # there is no data-plane retransmission): hand it to the
                 # stall/restart path rather than crashing the run
                 self._drop_boundary(drain)
-                return False, {"suspects": []}, self._committed
+                return False, {"suspects": []}, self._seg_end
+            if now >= next_cut and self._boundary_wanted(worker_ids):
+                self._send_cut(b0 + nb, worker_ids)
+                next_cut = now + max(self.cfg.heartbeat_interval,
+                                     self.cfg.poll)
             msg = self._recv()
             if msg is not None:
                 self._absorb(msg)
                 if msg.kind == "commit" and msg.payload >= b0:
+                    # the wedge deadline runs from the latest commit: a
+                    # long segment that makes progress never trips it
+                    deadline = time.monotonic() + self.cfg.segment_timeout
                     if self._refill is not None:
                         self._end_refill(msg.payload)
-                    if drain is None and msg.payload >= last:
-                        drain = Span("ftp.coord.drain", batch=last).open()
+                if drain is None and self._committed >= self._seg_end - 1:
+                    drain = Span("ftp.coord.drain",
+                                 batch=self._seg_end - 1).open()
+                # a round closes once every worker has passed it: a later
+                # ack or its seg_done proves that a worker ran it (per-
+                # sender FIFO), so a lost ack delays a round, never stalls
+                # the rounds after it. Those a cut left beyond the end
+                # never run
+                while r < len(rounds) and (
+                        rounds[r][0] >= self._seg_end
+                        or all(self._passed.get(d, -1) >= rounds[r][0]
+                               for d in worker_ids)):
+                    if rounds[r][0] < self._seg_end:
+                        self._inline_round(*rounds[r], overlap, part,
+                                           worker_ids, on_round)
+                    r += 1
             suspects = [dev for dev in worker_ids
                         if dev not in self._done
                         and now - self._last_hb[dev]
                         > self.proto.detect_timeout]
             if suspects:
                 self._drop_boundary(drain)
-                return False, {"suspects": suspects}, self._committed
-        self._boundary = {"batch": b0 + nb, "replicate_s": 0.0,
+                return False, {"suspects": suspects}, self._seg_end
+        self._boundary = {"batch": self._seg_end, "replicate_s": 0.0,
                           "drain_s": 0.0 if drain is None else drain.close()}
-        return True, dict(self._done), self._committed
+        return True, dict(self._done), self._seg_end
+
+    def _send_cut(self, planned_end: int, worker_ids: list) -> None:
+        """Ask stage 0 to cut the running segment, or, once it has chosen
+        the end, tell it to every other stage still running. Resent until
+        the segment ends: a cut lost on the wire would otherwise leave the
+        boundary at the horizon, or a stage waiting for a batch that
+        stage 0 never forwards."""
+        seg = self._cur_seg
+        if self._seg_end < planned_end:
+            for dev in worker_ids[1:]:
+                if dev not in self._done:
+                    self.transport.send(COORD, dev, "cut",
+                                        {"seg_id": seg, "at": self._seg_end})
+        elif worker_ids[0] not in self._done:
+            self.transport.send(COORD, worker_ids[0], "cut", {"seg_id": seg})
+
+    def _inline_round(self, batch: int, do_chain: bool, do_global: bool,
+                      overlap: bool, part: PartitionResult,
+                      worker_ids: list, on_round) -> None:
+        """Every worker has passed the in-segment round at ``batch``:
+        count and log it, and commit the durable state after a global
+        round (as ``_replicate`` does). ``on_round`` takes its counters
+        where every ack arrived; a round with an ack lost on the wire
+        gives no capacity sample (the worker's next report starts after
+        the lost one, and its seconds count at ``seg_done``)."""
+        acks = self._inline_acks.pop(batch, {})
+        self._control_totals["replications_inline"] += 1
+        kind = protocol.replication_kind(do_chain, do_global)
+        tag = " (in-segment)" + (" (overlapped)" if overlap else "")
+        if len(acks) < len(worker_ids):
+            self._log(f"{kind} replication @batch {batch}{tag}: only "
+                      f"{len(acks)}/{len(worker_ids)} acks")
+        else:
+            self._log(f"{kind} replication @batch {batch}{tag}")
+        if do_global:
+            # per-sender FIFO: every global_put of this round that was not
+            # lost is in the store (a short round makes the floor
+            # conservative, as in ``_replicate``)
+            self._durable_sync(part, worker_ids)
+        if on_round is not None and len(acks) == len(worker_ids):
+            on_round(batch, acks)
 
     def _probe(self, worker_ids: list) -> dict:
         """§III-F: on timer expiry the central node probes every worker."""
@@ -2214,10 +2459,13 @@ class Coordinator:
             for w in self.workers.values():
                 if self.transport.is_alive(w.dev):
                     self.transport.send(COORD, w.dev, "stop", {})
-                w.shutdown()
             for w in self.workers.values():
+                # a worker ends at its `stop`, having handled what came
+                # before it: a replica a peer shipped in the last segment
+                # lands in its store, as it would at a drained point
                 if w.ident is not None:      # never started -> nothing to join
                     w.join(timeout=5.0)
+                w.shutdown()
         return LiveResult(
             losses=self.losses, loss_log=self.loss_log,
             partitions=partitions, events=self.events,
@@ -2233,7 +2481,9 @@ class Coordinator:
                 dev: sorted(d.id for d in w.stash.newest().devices())
                 for dev, w in self.workers.items() if w.stash is not None},
             stage_stats=list(self.stage_stats),
-            control_points=list(self.control_points))
+            control_points=list(self.control_points),
+            replications_inline=self._control_totals["replications_inline"],
+            drains=self._control_totals["drains"])
 
     def _run_protocol(self, est, part, partitions, worker_ids, profile,
                       state):
@@ -2255,10 +2505,20 @@ class Coordinator:
                 # next segment's planning and messages included, to its
                 # first commit
                 self._refill = Span("ftp.coord.refill", batch=b0).open()
-            pts = [p for p in proto.control_points(B) if p > b0]
-            nxt = pts[0] if pts else B
-            ok, info, committed = self._run_segment(b0, nxt - b0, part,
-                                                    worker_ids)
+            # the segment runs to the next point that drains; each
+            # replication point inside it is an in-segment round, whose
+            # acks bring the capacity samples of the batches since the
+            # previous sample
+            since = b0
+
+            def sample(batch, stats):
+                nonlocal since
+                self._sample_capacities(est, part, worker_ids, profile,
+                                        since, stats)
+                since = batch
+
+            ok, info, nxt = self._run_segment(
+                b0, proto.next_drain(b0, B) - b0, part, worker_ids, sample)
             if not ok:
                 # ---- §III-F failure path --------------------------------
                 state.enter_recovery()
@@ -2302,39 +2562,14 @@ class Coordinator:
                 continue
 
             # ---- capacity samples (Eqs. 1-3) ----------------------------
-            # Eq. 1 is a ratio against the central node's CURRENT speed.
-            # The startup profile times layers eagerly, but the compiled
-            # StageExecutor runs far faster than that, so raw
-            # measured/profile ratios would make every worker look fast
-            # relative to a central pinned at C_0 = 1. Calibrate by the
-            # central worker's own measured-vs-profile factor (the spec
-            # branch normalizes by c0 the same way).
-            def _median_bt(dev):
-                stats = info[dev]
-                # median per-batch time: robust to first-call tracing
-                # and thread-scheduling spikes
-                bt = stats.get("batch_times") or [
-                    stats["busy_s"] / max(stats["nb"], 1)]
-                return float(np.median(bt))
-
-            a0, e0 = part.ranges[0]
-            ref0 = float(np.sum(profile.exec_times[a0:e0 + 1]))
-            kappa = _median_bt(worker_ids[0]) / max(ref0, 1e-12)
-            for i, dev in enumerate(worker_ids):
-                a, e = part.ranges[i]
-                if cfg.capacity_source == "spec":
-                    c0 = self.specs[worker_ids[0]].capacity_at(b0)
-                    meas = float(np.sum(profile.exec_times[a:e + 1])
-                                 * self.specs[dev].capacity_at(b0)
-                                 / max(c0, 1e-12))
-                else:
-                    meas = _median_bt(dev) / max(kappa, 1e-12)
-                est.update(i, meas, a, e)
+            sample(nxt, {dev: dict(st, batch_times=st["tail_times"])
+                         for dev, st in info.items()})
             state.committed_forward_id = nxt - 1
             state.committed_backward_id = nxt - 1
             b0 = nxt
             if b0 >= B:
                 break
+            self._control_totals["drains"] += 1
 
             # ---- boundary liveness sweep (§III-F fault timer) -----------
             # the paper's fault timer runs continuously at the central
@@ -2427,6 +2662,7 @@ class Coordinator:
             # average these into the fleet's final model; the aggregation
             # bench evaluates accuracy on them). Barrier: the snapshot
             # below reads the store immediately, so never overlap it
+            self._control_totals["drains"] += 1
             self._replicate(b0, False, True, part, worker_ids,
                             barrier=True)
             L = self.chain.num_layers
@@ -2438,6 +2674,40 @@ class Coordinator:
                     snap[j] = np.asarray(got[1])
             self.final_flats = snap if len(snap) == L else None
         return est, partitions
+
+    def _sample_capacities(self, est, part, worker_ids, profile, b: int,
+                           stats: dict) -> None:
+        """One Eq. 1-3 capacity sample per worker from ``stats`` (dev ->
+        counters of its batches since the previous sample, which began at
+        batch ``b``).
+
+        Eq. 1 is a ratio against the central node's CURRENT speed. The
+        startup profile times layers eagerly, but the compiled
+        StageExecutor runs far faster than that, so raw measured/profile
+        ratios would make every worker look fast relative to a central
+        pinned at C_0 = 1. Calibrate by the central worker's own
+        measured-vs-profile factor (the spec branch normalizes by c0 the
+        same way)."""
+        def _median_bt(dev):
+            st = stats[dev]
+            # median per-batch time: robust to first-call tracing
+            # and thread-scheduling spikes
+            bt = st.get("batch_times") or [st["busy_s"] / max(st["nb"], 1)]
+            return float(np.median(bt))
+
+        a0, e0 = part.ranges[0]
+        ref0 = float(np.sum(profile.exec_times[a0:e0 + 1]))
+        kappa = _median_bt(worker_ids[0]) / max(ref0, 1e-12)
+        for i, dev in enumerate(worker_ids):
+            a, e = part.ranges[i]
+            if self.cfg.capacity_source == "spec":
+                c0 = self.specs[worker_ids[0]].capacity_at(b)
+                meas = float(np.sum(profile.exec_times[a:e + 1])
+                             * self.specs[dev].capacity_at(b)
+                             / max(c0, 1e-12))
+            else:
+                meas = _median_bt(dev) / max(kappa, 1e-12)
+            est.update(i, meas, a, e)
 
     def _handle_shortfall(self, shortfall, worker_ids, part, est, profile,
                           state, partitions):

@@ -5,13 +5,16 @@ events happen and WHAT they decide, used by both runtimes:
   * ``runtime/live.py``      — executes the same decisions on real JAX
                                computations over ``runtime/transport.py``.
 
-Both runtimes iterate the batch axis in control-free segments delimited by
-``control_points`` and apply control events (replication cadence from
-``core/replication.py``, dynamic re-partition §III-D, failure recovery
-§III-F) at batch boundaries with a pipeline drain.  For the simulator this
-is a documented approximation; for the live runtime it is the actual
-execution strategy, which is what keeps the two in lock-step: same inputs
--> same partitions, same replication schedule, same recovery plan.
+Both runtimes iterate the batch axis in segments and apply control events
+(replication cadence from ``core/replication.py``, dynamic re-partition
+§III-D, failure recovery §III-F) at the ``control_points`` batch
+boundaries. ``ProtocolConfig.drains`` decides which of those points end a
+segment with a pipeline drain: only those whose event needs an empty
+pipeline. A point that only replicates runs inside the segment, each
+worker snapshotting its slice at its own batch boundary. For the simulator
+the drain is a documented approximation; for the live runtime it is the
+actual execution strategy, which is what keeps the two in lock-step: same
+inputs -> same partitions, same replication schedule, same recovery plan.
 
 Decision helpers delegate to the unit-tested core modules
 (``core/partition.py``, ``core/capacity.py``, ``core/redistribution.py``,
@@ -76,14 +79,17 @@ class ProtocolConfig:
     def replication_blocking_cost(self, chain_c: float,
                                   global_c: float, *,
                                   seeding: bool = False,
-                                  barrier: bool = False) -> float:
-        """Wall-clock a replication round holds the pipeline drained for.
-        Drain mode pays the full serialized transfer; overlap mode pays
-        only the snapshot + ack round trip (the bytes ride the next
-        segment) — capped at the drain cost, since snapshotting a slice
-        can never hold the pipeline longer than also shipping it."""
-        if self.replication_mode(seeding=seeding,
-                                 barrier=barrier) == "overlap":
+                                  barrier: bool = False,
+                                  inline: bool = False) -> float:
+        """Wall-clock a replication round holds the pipeline for.
+        A drained round in drain mode pays the full serialized transfer;
+        overlap mode and an in-segment round (``inline``: no drain, each
+        worker snapshots between two of its own ops) pay only the
+        snapshot + ack round trip — capped at the drain cost, since
+        snapshotting a slice can never hold the pipeline longer than also
+        shipping it."""
+        if inline or self.replication_mode(seeding=seeding,
+                                           barrier=barrier) == "overlap":
             return min(self.commit_rtt, chain_c + global_c)
         return chain_c + global_c
 
@@ -101,11 +107,44 @@ class ProtocolConfig:
         return (self.fleet_every > 0 and batch > 0
                 and batch % self.fleet_every == 0)
 
+    def drains(self, batch: int, *, dynamic: bool = True) -> bool:
+        """Does the control point at ``batch`` need an empty pipeline? ONE
+        decision shared by the live coordinator and the simulator. A point
+        drains where it seeds (batch 0) or where a re-partition
+        (``dynamic``) or a fleet barrier is due. A point that only
+        replicates does not drain: its round runs inside the segment. The
+        final collect lies at the horizon, where the last segment ends
+        anyway, and a boundary the coordinator asks for (a stop, a joiner
+        to admit) cuts the running segment at its next point
+        (docs/protocol.md §11)."""
+        return (batch <= 0 or (dynamic and self.repartition_due(batch))
+                or self.fleet_due(batch))
+
+    def next_drain(self, b0: int, num_batches: int) -> int:
+        """End of the segment that starts at ``b0``: the first control
+        point after it that drains, else ``num_batches``."""
+        for p in self.control_points(num_batches):
+            if p > b0 and self.drains(p):
+                return p
+        return num_batches
+
+    def inline_points(self, b0: int, end: int) -> list[tuple[int, bool,
+                                                             bool]]:
+        """``(batch, chain, global)`` of each replication point strictly
+        inside the segment ``[b0, end)``: rounds run in the segment."""
+        pts = set()
+        for every in (self.chain_every, self.global_every):
+            if every > 0:
+                pts.update(range((b0 // every + 1) * every, end, every))
+        return [(p, *self.replication_due(p)) for p in sorted(pts)]
+
     def control_points(self, num_batches: int, *, dynamic: bool = True,
                        extra: Sequence[int] = ()) -> list[int]:
-        """Sorted batch indices (< num_batches) where the pipeline drains for
-        a control event. ``dynamic=False`` drops the re-partition points
-        (static baselines: PipeDream / ResPipe)."""
+        """Sorted batch indices (< num_batches) of the control events:
+        the replication cadence, fleet barriers, re-partitions and
+        ``extra``. ``drains`` says which of them end a segment.
+        ``dynamic=False`` drops the re-partition points (static
+        baselines: PipeDream / ResPipe)."""
         pts = set(extra)
         for k in range(1, num_batches // self.chain_every + 1):
             pts.add(k * self.chain_every)
@@ -122,6 +161,12 @@ class ProtocolConfig:
 
 
 # --------------------------- decision helpers ----------------------------
+
+def replication_kind(do_chain: bool, do_global: bool) -> str:
+    """The tiers of one replication round, as both runtimes log it."""
+    return ("chain+global" if do_chain and do_global
+            else "chain" if do_chain else "global")
+
 
 def aggregation_ready(live: Sequence[int], arrived: Sequence[int],
                       waited: float,

@@ -8,9 +8,11 @@ edge cluster (virtual clock). Reproduces the paper's speed/fault experiments:
     redistribution vs ResPipe take-over policy; Table III / Fig. 6),
   * baselines: static-PipeDream partitioning, single-device training.
 
-Within control-free segments the pipeline is simulated exactly; control
-events (replication, re-partition, recovery) happen at batch boundaries with
-a drain — a small, documented approximation (DESIGN.md §6).
+Within a segment the pipeline is simulated exactly, including the
+replication rounds that run inside it (each stage pauses after its backward
+of the batch before the point); control events that need an empty pipeline
+(re-partition, recovery) happen at batch boundaries with a drain — a small,
+documented approximation (DESIGN.md §6).
 
 Protocol sharing: every control DECISION (when to replicate/re-partition,
 which partition, which redistribution plans) comes from
@@ -18,9 +20,10 @@ which partition, which redistribution plans) comes from
 against real JAX stage computations. This simulator only adds the virtual
 clock: it prices the shared decisions with ``protocol.chain_cost`` /
 ``global_cost`` / ``redistribution_cost`` instead of paying them in
-wall-clock. Because both runtimes drain at the same
-``ProtocolConfig.control_points`` and call the same planners, the simulator
-PREDICTS what the live runtime EXECUTES (see tests/test_live_runtime.py).
+wall-clock. Because both runtimes drain where ``ProtocolConfig.drains``
+says, replicate inside the segment elsewhere, and call the same planners,
+the simulator PREDICTS what the live runtime EXECUTES (see
+tests/test_live_runtime.py).
 """
 from __future__ import annotations
 
@@ -94,9 +97,13 @@ class PipelineSimulator:
     # ---------------- exact 1F1B segment simulation ---------------------
 
     def _segment(self, part: PartitionResult, worker_ids: list[int],
-                 num_batches: int, t0: float) -> tuple[np.ndarray, float]:
+                 num_batches: int, t0: float,
+                 pauses: Optional[dict] = None) -> tuple[np.ndarray, float]:
         """Simulate `num_batches` through the pipeline; returns (completion
-        times at stage 0, drain end time)."""
+        times at stage 0, drain end time). ``pauses`` maps a batch index k
+        of the segment to the seconds every stage spends on an in-segment
+        replication right after its backward of batch k - 1."""
+        pauses = pauses or {}
         cfg = self.cfg
         N = len(worker_ids)
         caps = self._caps_now()[worker_ids]
@@ -111,7 +118,8 @@ class PipelineSimulator:
             comm[i] = cfg.profile.out_bytes[ranges[i][1]] / bw
 
         if N == 1:
-            done = t0 + np.cumsum(np.full(num_batches, fwd_t[0] + bwd_t[0]))
+            pause = np.array([pauses.get(k, 0.0) for k in range(num_batches)])
+            done = t0 + np.cumsum(fwd_t[0] + bwd_t[0] + pause)
             return done, float(done[-1]) if num_batches else t0
 
         ops = [list(sched.stage_schedule(s, N, num_batches)) for s in range(N)]
@@ -144,7 +152,7 @@ class PipelineSimulator:
                         if dep is None:
                             break
                         done = max(dep, free[s]) + bwd_t[s]
-                        free[s] = done
+                        free[s] = done + pauses.get(op.batch + 1, 0.0)
                         if s > 0:
                             bwd_ready[s - 1][op.batch] = done + comm[s - 1]
                         else:
@@ -191,18 +199,42 @@ class PipelineSimulator:
         for d in cfg.devices:                          # capacity drift points
             for b, _ in d.capacity_schedule:
                 extra.add(b)
-        points = proto.control_points(cfg.num_batches,
-                                      dynamic=(cfg.policy == "ftpipehd"),
-                                      extra=sorted(extra))
-        points = points + [cfg.num_batches]
+        dynamic = cfg.policy == "ftpipehd"
+        # segments end where the shared decision drains (and at the
+        # simulator's own failure and capacity-drift points); replication
+        # points between them run inside the segment
+        ends = [p for p in proto.control_points(cfg.num_batches,
+                                                dynamic=dynamic,
+                                                extra=sorted(extra))
+                if p in extra or proto.drains(p, dynamic=dynamic)]
+        ends.append(cfg.num_batches)
         failed_done = False
+        suffix = (" (overlapped)" if proto.replication_mode() == "overlap"
+                  else "")
 
-        for nxt in points:
+        for nxt in ends:
             if nxt <= b0:
                 continue
             n_seg = nxt - b0
-            seg_done, t_end = self._segment(part, worker_ids, n_seg, t)
+            pauses, rounds = {}, []
+            for p, do_chain, do_global in proto.inline_points(b0, nxt):
+                cc = (protocol.chain_cost(cfg.profile, cfg.bandwidth,
+                                          part, worker_ids)
+                      if do_chain else 0.0)
+                gc = (protocol.global_cost(cfg.profile, cfg.bandwidth,
+                                           part, worker_ids)
+                      if do_global else 0.0)
+                pauses[p - b0] = proto.replication_blocking_cost(
+                    cc, gc, inline=True)
+                rounds.append((p, protocol.replication_kind(do_chain,
+                                                            do_global)))
+            seg_done, t_end = self._segment(part, worker_ids, n_seg, t,
+                                            pauses)
             batch_done[b0:b0 + n_seg] = seg_done
+            for p, kind in rounds:
+                events.append((float(seg_done[p - b0 - 1]),
+                               f"{kind} replication {pauses[p - b0]:.3f}s "
+                               f"(in-segment){suffix}"))
             t = t_end
             b0 = nxt
             if b0 >= cfg.num_batches:
@@ -262,10 +294,7 @@ class PipelineSimulator:
                 # hold the drain for the snapshot+ack round trip — the
                 # bytes ride the next segment's compute
                 c = proto.replication_blocking_cost(cc, gc)
-                mode = proto.replication_mode()
-                kind = ("chain+global" if do_chain and do_global
-                        else "chain" if do_chain else "global")
-                suffix = " (overlapped)" if mode == "overlap" else ""
+                kind = protocol.replication_kind(do_chain, do_global)
                 events.append((t, f"{kind} replication {c:.3f}s{suffix}"))
                 t += c
 

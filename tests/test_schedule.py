@@ -17,6 +17,38 @@ def test_schedule_invariants(n, stage, num_batches):
     assert bwd == list(range(num_batches))
 
 
+def _one_f_one_b(stage, n, num_batches):
+    """The 1F1B loop, written out: the reference ``stage_op`` computes."""
+    warm = min(n - stage, num_batches)
+    ops, next_f, next_b = [("fwd", b) for b in range(warm)], warm, 0
+    while next_b < num_batches:
+        ops.append(("bwd", next_b))
+        next_b += 1
+        if next_f < num_batches:
+            ops.append(("fwd", next_f))
+            next_f += 1
+    return ops
+
+
+@settings(max_examples=80, deadline=None)
+@given(st.integers(1, 8), st.integers(0, 7), st.integers(0, 64))
+def test_stage_op_walks_the_1f1b_loop(n, stage, num_batches):
+    """``stage_op`` (and ``stage_schedule`` built on it) gives op ``idx``
+    of the loop without building it; ``next_forward`` the first forward
+    at or after it."""
+    stage = min(stage, n - 1)
+    ref = _one_f_one_b(stage, n, num_batches)
+    assert [(o.kind, o.batch) for o in
+            sc.stage_schedule(stage, n, num_batches)] == ref
+    for idx in range(len(ref) + 1):
+        if idx < len(ref):
+            assert (sc.stage_op(stage, n, num_batches, idx).kind,
+                    sc.stage_op(stage, n, num_batches, idx).batch) \
+                == ref[idx]
+        assert sc.next_forward(stage, n, num_batches, idx) == next(
+            (b for k, b in ref[idx:] if k == "fwd"), None)
+
+
 @settings(max_examples=40, deadline=None)
 @given(st.integers(2, 8), st.integers(0, 100))
 def test_vertical_sync_version(n, b):

@@ -59,8 +59,13 @@ def test_replication_spikes_in_batch_times():
     r = _sim(DeviceSpec.raspberry_trio(), n=220).run()
     bt = r.batch_times
     base = np.median(bt[20:45])
-    assert bt[50] > base                  # chain replication at batch 50
-    assert bt[100] > bt[50] * 0.99        # chain+global at 100 costs more
+    # the chain round at batch 50 runs inside the segment: each stage
+    # pauses after its backward of batch 49, which shows in the next
+    # completions
+    chain_spike = max(bt[50:52])
+    assert chain_spike > base
+    # chain+global at 100 shares a drained re-partition point: costs more
+    assert bt[100] > chain_spike
 
 
 def test_fault_recovery_ftpipehd_vs_respipe():

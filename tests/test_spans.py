@@ -30,9 +30,12 @@ def test_span_closes_when_its_block_raises():
     assert counts["x_s"] >= 0
 
 
-def _run(tmp_path=None):
+def _run(tmp_path=None, drained=True):
     """A small MLP run on the queue transport, traced by the profiler
-    where ``tmp_path`` is given."""
+    where ``tmp_path`` is given. ``drained``: every replication point also
+    hosts a re-partition (one that adopts nothing, the partition being
+    static), so the pipeline drains there; else every point is an
+    in-segment round and the run is one segment."""
     import jax
 
     from repro.run import RunConfig, start_run
@@ -46,9 +49,12 @@ def _run(tmp_path=None):
             num_workers=WORKERS, num_batches=B, lr=0.1,
             protocol=ProtocolConfig(chain_every=CHAIN_EVERY,
                                     global_every=2 * CHAIN_EVERY,
-                                    repartition_first_at=10_000,
-                                    repartition_every=10_000,
-                                    detect_timeout=2.0)),
+                                    repartition_first_at=(
+                                        CHAIN_EVERY if drained else 10_000),
+                                    repartition_every=(
+                                        CHAIN_EVERY if drained else 10_000),
+                                    detect_timeout=2.0),
+            static_partition=True),
         transport="queue")
     if tmp_path is not None:
         jax.profiler.start_trace(str(tmp_path))
@@ -62,10 +68,32 @@ def _run(tmp_path=None):
 
 
 @pytest.mark.live
-def test_counters_of_a_run():
-    run, res = _run()
+@pytest.mark.parametrize("drained", [True, False],
+                         ids=["drained", "in-segment"])
+def test_counters_of_a_run(drained):
+    run, res = _run(drained=drained)
+    chain = run.status()["chains"][0]
+    rounds = list(range(CHAIN_EVERY, B, CHAIN_EVERY))
+    for dev, tot in chain["stages"].items():
+        mine = [s for s in res.stage_stats if s["dev"] == dev]
+        assert tot["batches"] == B
+        for k in ("busy_s", "wait_s", "host_s"):
+            assert tot[k] == pytest.approx(sum(s[k] for s in mine))
+            assert tot[k] >= 0
+    assert {s["dev"] for s in res.stage_stats} == set(range(WORKERS))
+    if not drained:
+        # one segment: no control point drains, every round runs in it
+        # and its ack reports the worker's counters since the last one
+        assert res.control_points == [] and res.drains == 0
+        assert res.replications_inline == len(rounds)
+        assert chain["control"]["replications_inline"] == len(rounds)
+        assert chain["control"]["drains"] == 0
+        assert len(res.stage_stats) == (len(rounds) + 1) * WORKERS
+        assert sum(s["nb"] for s in res.stage_stats) == B * WORKERS
+        return
+    assert res.drains == len(rounds) and res.replications_inline == 0
     points = [cp["batch"] for cp in res.control_points]
-    assert points == list(range(CHAIN_EVERY, B, CHAIN_EVERY))
+    assert points == rounds
     for cp in res.control_points:
         k = cp["batch"]
         assert cp["t"] == res.commit_times[k]
@@ -76,17 +104,9 @@ def test_counters_of_a_run():
         assert cp["replicate_s"] > 0 and cp["refill_s"] > 0
     segments = B // CHAIN_EVERY
     assert len(res.stage_stats) == segments * WORKERS
-    assert {s["dev"] for s in res.stage_stats} == set(range(WORKERS))
-    chain = run.status()["chains"][0]
     assert chain["control"]["points"] == len(points)
     assert chain["control"]["refill_s"] == pytest.approx(
         sum(cp["refill_s"] for cp in res.control_points))
-    for dev, tot in chain["stages"].items():
-        mine = [s for s in res.stage_stats if s["dev"] == dev]
-        assert tot["batches"] == B
-        for k in ("busy_s", "wait_s", "host_s"):
-            assert tot[k] == pytest.approx(sum(s[k] for s in mine))
-            assert tot[k] >= 0
 
 
 def _spans(log_dir):
@@ -105,18 +125,30 @@ def _spans(log_dir):
 
 
 @pytest.mark.live
-def test_spans_reach_the_trace_and_do_not_nest(tmp_path):
-    _, res = _run(tmp_path)
+@pytest.mark.parametrize("drained", [True, False],
+                         ids=["drained", "in-segment"])
+def test_spans_reach_the_trace_and_do_not_nest(tmp_path, drained):
+    _, res = _run(tmp_path, drained=drained)
     spans = _spans(str(tmp_path))
     names = {n for n, *_ in spans}
     for d in range(WORKERS):
         assert {f"ftp.w{d}.fwd", f"ftp.w{d}.step",
                 f"ftp.w{d}.replicate"} <= names
-    assert {"ftp.coord.drain", "ftp.coord.replicate",
-            "ftp.coord.refill"} <= names
+        # the start-up round, then one per replication point
+        assert [n for n, *_ in spans].count(f"ftp.w{d}.replicate") \
+            == B // CHAIN_EVERY
     steps = [s for n, *_, s in spans if n == "ftp.w0.step"]
     assert sorted(s["batch"] for s in steps) == list(range(B))
-    assert {s["seg"] for s in steps} == set(range(1, B // CHAIN_EVERY + 1))
+    if drained:
+        assert {"ftp.coord.drain", "ftp.coord.replicate",
+                "ftp.coord.refill"} <= names
+        assert {s["seg"] for s in steps} \
+            == set(range(1, B // CHAIN_EVERY + 1))
+    else:
+        # the run's end is its only drain, and nothing refills
+        assert "ftp.coord.refill" not in names
+        assert [n for n, *_ in spans].count("ftp.coord.drain") == 1
+        assert {s["seg"] for s in steps} == {1}
     # within one thread (a worker's prefix, or the coordinator's) spans
     # follow one another
     for prefix in [f"ftp.w{d}." for d in range(WORKERS)] + ["ftp.coord."]:
