@@ -27,7 +27,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmarks.chip import check, registry  # noqa: E402
-from benchmarks.chip.run import SEED_MOD  # noqa: E402
+from benchmarks.chip.run import SEED_MOD, prepare  # noqa: E402
 
 
 FAULTS = ("half_batch", "no_exchange", "altered_loss", "latest_weights")
@@ -47,13 +47,11 @@ def stage_ends(num_layers: int, stages: int) -> tuple:
 def _exact(cell_json: str, seed: int):
     """The seed's weights, batches and reference steps, kept for the next
     fault on the same seed."""
-    cell = json.loads(cell_json)
-    ref = registry.load_reference(
-        registry.load_config(cell["config"])["reference"])
+    cell, ref = prepare(json.loads(cell_json))
     wseed = seed % SEED_MOD
-    params = ref.init_params(wseed)
+    params = ref.init_params(wseed, **cell["spec"])
     data = ref.make_batches(wseed, cell["check_steps"], cell["batch"],
-                            cell["image_hw"], cell["noise"])
+                            **cell["spec"])
     exact = ref.first_steps(params, data, lr=cell["lr"],
                             n_stages=cell["workers"],
                             steps=cell["check_steps"])
@@ -76,7 +74,7 @@ def readings(cell: dict, seed: int, precision: str | None = None,
         half = cell["batch"] // 2
         low_data = [{k: v[:half] for k, v in b.items()} for b in data]
     elif fault == "no_exchange":
-        zero_after = stage_ends(ref.NUM_LAYERS, cell["workers"])
+        zero_after = stage_ends(len(params), cell["workers"])
     elif fault == "latest_weights":
         n_low = 1       # every batch on the newest weights, none stashed
     elif fault not in (None, "altered_loss"):

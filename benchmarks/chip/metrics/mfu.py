@@ -9,5 +9,5 @@ def read(ctx):
         return None
     rate = window.samples_per_s(ctx.result.commit_times, ctx.t_open,
                                 ctx.seconds, ctx.cell["batch"])
-    flops = 3.0 * ctx.reference.forward_flops_per_sample(ctx.cell["image_hw"])
+    flops = 3.0 * ctx.reference.forward_flops_per_sample(**ctx.cell["spec"])
     return 100.0 * flops * rate / (ctx.chips * ctx.peaks["flops_per_s"])

@@ -33,6 +33,15 @@ IR_SPEC = [(1, 16, 1, 1), (6, 24, 2, 1), (6, 32, 3, 2), (6, 64, 4, 2),
 STEM_CH, HEAD_CH, NUM_CLASSES = 32, 1280, 10
 NUM_LAYERS = 2 + sum(n for _, _, n, _ in IR_SPEC)
 
+# The WorkloadSpec fields a configuration of this model sets; every
+# function below takes them by keyword and ignores the others.
+SPEC_KEYS = ("image_hw", "noise")
+# A cell cut to a size the CPU runs in seconds: 8 images of 16x16 and the
+# program's analytic profile in place of timing every layer. (At 8x8 the
+# last blocks normalise over 8 values, and rounding grows over the
+# compared steps past the limits.)
+CPU_CUT = {"batch": 8, "image_hw": 16, "profile": "mobilenetv2"}
+
 
 def layer_meta() -> list[dict]:
     meta = [{"kind": "stem", "cin": 3, "cout": STEM_CH, "stride": 1, "t": 1}]
@@ -83,14 +92,14 @@ def _init_from_key(key):
     return layers
 
 
-def init_params(seed: int) -> list:
+def init_params(seed: int, **_) -> list:
     """Per-layer parameter pytrees, float32, made on the device in one
     jitted call."""
     return _init_from_key(jax.random.PRNGKey(seed))
 
 
-def make_batches(seed: int, count: int, batch: int, image_hw: int,
-                 noise: float) -> list[dict]:
+def make_batches(seed: int, count: int, batch: int, *, image_hw: int,
+                 noise: float, **_) -> list[dict]:
     """``count`` labelled batches: a random template per class plus
     Gaussian noise, NHWC float32."""
     rng = np.random.default_rng(seed)
@@ -219,7 +228,7 @@ def flat_layers(params) -> list[np.ndarray]:
 
 # ------------------------------ cost ------------------------------
 
-def forward_flops_per_sample(image_hw: int) -> float:
+def forward_flops_per_sample(*, image_hw: int, **_) -> float:
     """Multiply-adds x 2 of one sample's forward pass: every convolution
     and the classifier. A t = 1 block has no expansion convolution."""
     total, hw = 0.0, image_hw
@@ -241,7 +250,7 @@ def forward_flops_per_sample(image_hw: int) -> float:
     return float(total)
 
 
-def param_count() -> int:
+def param_count(**_) -> int:
     """Parameters of the whole model, from the shapes alone."""
     shapes = jax.eval_shape(_init_from_key, jax.random.PRNGKey(0))
     return int(sum(np.prod(a.shape) for a in jax.tree.leaves(shapes)))

@@ -29,6 +29,7 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -60,7 +61,7 @@ class NoChip(RuntimeError):
 @dataclasses.dataclass
 class Context:
     """What a metric's reader may read."""
-    cell: dict
+    cell: dict                     # with its model_kind and spec
     reference: object
     result: object                 # repro.runtime.live.LiveResult
     t_open: float                  # coordinator clock, seconds
@@ -91,10 +92,9 @@ def run_config(cell: dict, seed: int, *, kill=None, profile=None,
     never = 10 ** 9
     return RunConfig(
         workload=WorkloadSpec(kind=cell["model_kind"], seed=seed,
-                              image_hw=cell["image_hw"],
                               batch_size=cell["batch"],
                               num_data_batches=cell["data_batches"],
-                              noise=cell["noise"]),
+                              **cell["spec"]),
         live=LiveConfig(
             num_workers=cell["workers"],
             num_batches=num_batches or cell["horizon_batches"],
@@ -109,6 +109,28 @@ def run_config(cell: dict, seed: int, *, kill=None, profile=None,
             capacity_source="spec", kill=kill, profile=profile,
             segment_timeout=cell["segment_timeout"]),
         transport="queue")
+
+
+def prepare(cell: dict):
+    """The cell with its model's ``model_kind`` and ``spec`` (the
+    ``WorkloadSpec`` fields of ``registry.spec_of``), and the model's
+    reference."""
+    config = registry.load_config(cell["config"])
+    ref = registry.load_reference(config["reference"])
+    return dict(cell, model_kind=config["model"]["kind"],
+                spec=registry.spec_of(cell, config, ref)), ref
+
+
+def analytic_profile(cell: dict):
+    """The program's analytic profile the cell names, or None: given the
+    batch and those of the cell's ``spec`` its signature takes."""
+    if "profile" not in cell:
+        return None
+    from repro.runtime.devices import WorkloadProfile
+    make = getattr(WorkloadProfile, cell["profile"])
+    takes = inspect.signature(make).parameters
+    args = dict(cell["spec"], batch=cell["batch"])
+    return make(**{k: v for k, v in args.items() if k in takes})
 
 
 def wait_committed(run, batch: int, deadline_s: float) -> float:
@@ -189,22 +211,20 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     from benchmarks.chip import compiles, peaks as peaks_mod, probe
     from benchmarks.chip import trace as trace_mod
 
-    config = registry.load_config(cell["config"])
-    ref = registry.load_reference(config["reference"])
-    cell = dict(cell, model_kind=config["model"]["kind"])
+    cell, ref = prepare(cell)
     wseed = seed % SEED_MOD
 
     devices = jax.devices()
     dev0 = devices[0]
-    peaks = None
-    if require_chip:
-        try:
-            peaks = peaks_mod.peaks_for(dev0.platform, dev0.device_kind)
-        except peaks_mod.UnknownDevice as exc:
+    try:
+        peaks = peaks_mod.peaks_for(dev0.platform, dev0.device_kind)
+    except peaks_mod.UnknownDevice as exc:
+        if require_chip:
             raise NoChip(str(exc)) from exc
-        if len(devices) < cell["chips"]:
-            raise NoChip(f"the cell asks for {cell['chips']} chips, JAX "
-                         f"sees {len(devices)}")
+        peaks = None
+    if require_chip and len(devices) < cell["chips"]:
+        raise NoChip(f"the cell asks for {cell['chips']} chips, JAX sees "
+                     f"{len(devices)}")
     used = devices[:cell["chips"]]
     if cache_dir is not None:
         jax.config.update("jax_compilation_cache_dir", str(cache_dir))
@@ -218,16 +238,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     log(f"compile cache: {cache_dir}, min compile time 0 s")
 
     from repro.run import Run
-    from repro.runtime.devices import WorkloadProfile
     from repro.runtime.live import VerticalSyncStash, Worker
 
     with compiles.CompileLog() as clog:
-        profile = None
-        if "profile" in cell:
-            # the program's analytic profile: the partitions it leads to
-            # are the same in every run, which a measured one's are not
-            profile = getattr(WorkloadProfile, cell["profile"])(
-                batch=cell["batch"], image_hw=cell["image_hw"])
+        # an analytic profile leads to the same partitions in every run,
+        # which a measured one does not
+        profile = analytic_profile(cell)
         if "kill" in cell:
             rehearse(cell, wseed, profile)
             gc.collect()
@@ -297,7 +313,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
             f"the survivors' first commit {after}; re-run batches {rerun}")
 
     # ---- the check: the run's first steps against the reference ----
-    params0 = ref.init_params(wseed)
+    params0 = ref.init_params(wseed, **cell["spec"])
     sizes = ref.leaf_sizes(params0)
     layer_sizes = [sum(s) for s in sizes]
     workers0 = list(range(cell["workers"]))
@@ -341,8 +357,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     del run, cap, handoff
     gc.collect()
 
-    data = ref.make_batches(wseed, steps, cell["batch"], cell["image_hw"],
-                            cell["noise"])
+    data = ref.make_batches(wseed, steps, cell["batch"], **cell["spec"])
     ref_run = ref.first_steps(params0, data, lr=cell["lr"],
                               n_stages=cell["workers"], steps=steps)
     ref_state = {"losses": ref_run["losses"],

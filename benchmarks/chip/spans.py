@@ -7,10 +7,10 @@ planes' clock: ``ftp.w{d}.<kind>`` in worker ``d``'s thread (``fwd``,
 ``step``, ``wait``, ``replicate``, ``refit``) and ``ftp.coord.<kind>`` in
 the coordinator's (``drain``, ``replicate``, ``refill``, ``probe``,
 ``recover``). Worker ``d`` runs on chip ``d % n`` of the host's ``n``
-(``stage_executor.stage_device``). The same intervals are counted in
-``LiveResult.stage_stats`` and ``LiveResult.control_points`` on the
-coordinator's clock; a program without them (an older commit) leaves every
-function here returning None or nothing.
+(``stage_executor.stage_device``). The workers' intervals are also counted
+in ``LiveResult.stage_stats`` on the coordinator's clock; a program
+without them (an older commit) leaves every function here returning None
+or nothing.
 """
 from __future__ import annotations
 
@@ -21,25 +21,11 @@ from benchmarks.chip import trace as trace_mod
 
 WORKER = re.compile(r"^ftp\.w(\d+)\.(\w+)$")
 COORD = "ftp.coord."
-CONTROL = ("ftp.coord.drain", "ftp.coord.replicate", "ftp.coord.refill")
 PROGRAMS = {"fwd": "fwd_out", "step": "step_fn"}   # span kind -> program
 NO_SPAN = "host, no span"
 
 
 # ------------------------------ records ------------------------------
-
-def control_points_in_window(ctx) -> list[dict]:
-    """The run's control-point records whose batch k committed in the
-    window with batch k - 1 (the points ``control_point_ms`` averages)."""
-    inside = set(ctx.batches)
-    return [cp for cp in getattr(ctx.result, "control_points", None) or ()
-            if cp["batch"] in inside and cp["batch"] - 1 in inside]
-
-
-def mean_control_ms(ctx, key: str) -> float | None:
-    cps = control_points_in_window(ctx)
-    return 1000.0 * sum(cp[key] for cp in cps) / len(cps) if cps else None
-
 
 def stage_ms_per_batch(ctx, key: str) -> float | None:
     """Milliseconds of ``key`` over the workers' segments done in the
@@ -74,6 +60,13 @@ def worker_spans(trace) -> dict[int, list]:
     return out
 
 
+def worker_span_s(trace, kind: str) -> float:
+    """Seconds of every worker's ``ftp.w{d}.<kind>`` spans in the trace,
+    summed over workers."""
+    return sum(ev.dur for ev in program_spans(trace)
+               if (m := WORKER.match(ev.name)) and m.group(2) == kind)
+
+
 def coordinator_spans(trace, names=None) -> list:
     return [ev for ev in program_spans(trace) if ev.name.startswith(COORD)
             and (names is None or ev.name in names)]
@@ -81,36 +74,6 @@ def coordinator_spans(trace, names=None) -> list:
 
 def _length(intervals) -> float:
     return sum(e - s for s, e in intervals)
-
-
-def _intersect(a, b) -> float:
-    """Seconds common to two merged, sorted interval lists."""
-    i = j = 0
-    total = 0.0
-    while i < len(a) and j < len(b):
-        s, e = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
-        if e > s:
-            total += e - s
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def idle_in_control_share(trace) -> float | None:
-    """Percent of the chips' idle time in the window that falls inside a
-    coordinator ``drain``, ``replicate`` or ``refill`` span."""
-    control = trace_mod.union((ev.start, ev.end)
-                              for ev in coordinator_spans(trace, CONTROL))
-    if not control:
-        return None
-    idle = inside = 0.0
-    for d in trace.device_names():
-        gaps = trace_mod.idle_gaps(trace, d)
-        idle += _length(gaps)
-        inside += _intersect(gaps, control)
-    return 100.0 * inside / idle if idle > 0 else None
 
 
 def _attribute(gaps, layers) -> dict[str, float]:

@@ -14,12 +14,12 @@ for p in (ROOT, ROOT / "src"):
 
 def tiny(cell: dict, **over) -> dict:
     """A cell cut to a size the CPU runs in seconds: the same protocol,
-    cadence and checks, 8 images of 16x16 per batch, and the program's
-    analytic profile in place of timing every layer. (At 8x8 the last
-    blocks normalise over 8 values, and rounding grows over the compared
-    steps past the limits.)"""
+    cadence and checks, with the cut its model's reference states
+    (``CPU_CUT``)."""
+    from benchmarks.chip import registry
+    config = registry.load_config(cell["config"])
     out = json.loads(json.dumps(cell))
-    out.update(batch=8, image_hw=16, profile="mobilenetv2")
+    out.update(registry.load_reference(config["reference"]).CPU_CUT)
     out.update(over)
     return out
 

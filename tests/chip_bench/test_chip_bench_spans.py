@@ -1,5 +1,5 @@
 """The program's spans in a trace and the readers built on them, on small
-recorded traces: the control-point split, the workers' counters, the idle
+recorded traces: the workers' counters and replication rounds, the idle
 time put down to spans, and the recovery's probe and program loads."""
 import math
 import types
@@ -102,14 +102,15 @@ def test_worker_spans_sit_on_their_chips(steady_trace):
         "ftp.coord.drain", "ftp.coord.replicate", "ftp.coord.refill"]
 
 
-def test_idle_in_control_share(steady_trace):
-    # control spans cover [8.4, 10] ms: 1.6 ms of each chip's idle time,
-    # of 4 + 8 ms idle in all
-    assert math.isclose(spans.idle_in_control_share(steady_trace),
-                        100.0 * 3.2 / 12.0)
-    assert math.isclose(
-        _read("device.idle_in_control_share", trace=steady_trace),
-        100.0 * 3.2 / 12.0)
+def test_replicate_reader_sums_the_workers_rounds(steady_trace):
+    # worker 2's round over [3.5, 4.0] ms; the coordinator's replicate
+    # span is not a worker's
+    assert math.isclose(spans.worker_span_s(steady_trace, "replicate"),
+                        0.5e-3)
+    assert math.isclose(_read("stage.replicate_ms", trace=steady_trace,
+                              traced_batches=[1, 2]), 0.25)
+    assert _read("stage.replicate_ms", trace=steady_trace,
+                 traced_batches=[]) is None
 
 
 def test_idle_by_span(steady_trace):
@@ -157,8 +158,7 @@ def test_recovery_probe_and_loads(kill_trace):
 
 def test_trace_readers_find_nothing_without_spans(steady_trace):
     bare = _trace(NO_SPANS)
-    for metric in ("recover.probe_s", "recover.load_s",
-                   "device.idle_in_control_share"):
+    for metric in ("recover.probe_s", "recover.load_s"):
         assert _read(metric, trace=None) is None
         assert _read(metric, trace=bare) is None
     # a steady trace holds no recovery
@@ -169,23 +169,22 @@ def test_trace_readers_find_nothing_without_spans(steady_trace):
     assert spans.programs_in_spans(bare) == {0: 0.0}
 
 
+def test_replicate_reader_finds_nothing_without_spans():
+    bare = _trace(NO_SPANS)
+    assert _read("stage.replicate_ms", trace=None,
+                 traced_batches=[1, 2]) is None
+    assert _read("stage.replicate_ms", trace=bare,
+                 traced_batches=[1, 2]) is None
+    # a kill trace's workers run no round
+    assert _read("stage.replicate_ms", trace=_trace(KILL),
+                 traced_batches=[1, 2]) is None
+
+
 def _records():
-    cps = [{"batch": b, "t": 0.1 * b, "drain_s": 0.001 * b,
-            "replicate_s": 0.002, "refill_s": 0.010}
-           for b in (10, 15, 20, 25)]
     stats = [{"seg_id": i, "dev": d, "t_done": t, "nb": 5,
               "busy_s": 0.5, "wait_s": 0.25 * (d + 1), "host_s": 0.125}
              for i, t in enumerate((0.9, 1.5, 3.0, 3.1)) for d in (0, 1)]
-    return types.SimpleNamespace(control_points=cps, stage_stats=stats)
-
-
-def test_control_point_readers_average_the_window():
-    # batch 10's predecessor is outside the window, 25 is after it
-    ctx = dict(result=_records(), batches=list(range(10, 21)),
-               t_open=1.0, seconds=2.0)
-    assert math.isclose(_read("control_point.drain_ms", **ctx), 17.5)
-    assert math.isclose(_read("control_point.replicate_ms", **ctx), 2.0)
-    assert math.isclose(_read("control_point.refill_ms", **ctx), 10.0)
+    return types.SimpleNamespace(stage_stats=stats)
 
 
 def test_stage_readers_sum_the_window_per_batch():
@@ -197,14 +196,12 @@ def test_stage_readers_sum_the_window_per_batch():
     assert math.isclose(_read("stage.host_ms", **ctx), 1000 * 0.5 / 11)
 
 
-@pytest.mark.parametrize("metric", [
-    "control_point.drain_ms", "control_point.replicate_ms",
-    "control_point.refill_ms", "stage.wait_ms", "stage.host_ms"])
+@pytest.mark.parametrize("metric", ["stage.wait_ms", "stage.host_ms"])
 def test_record_readers_find_nothing_in_an_older_program(metric):
     # a LiveResult without the span counters, as an older commit returns
     older = types.SimpleNamespace(commit_times={10: 1.0})
     ctx = dict(result=older, batches=list(range(10, 21)), t_open=1.0,
                seconds=2.0)
     assert _read(metric, **ctx) is None
-    ctx["result"] = types.SimpleNamespace(control_points=[], stage_stats=[])
+    ctx["result"] = types.SimpleNamespace(stage_stats=[])
     assert _read(metric, **ctx) is None

@@ -1,5 +1,6 @@
 """The benchmark's yardstick without a chip: model FLOPs, the peaks table,
 window arithmetic, the trace reduction and the comparison."""
+import hashlib
 import json
 import math
 import types
@@ -19,15 +20,83 @@ def reference():
 
 def test_forward_flops_per_sample_at_32x32():
     # 0.176 GFLOP: a t = 1 block has no expansion convolution
-    assert reference().forward_flops_per_sample(32) == 175_952_896
+    assert reference().forward_flops_per_sample(image_hw=32) == 175_952_896
 
 
 def test_parameter_count_matches_the_configuration():
+    from benchmarks.chip import run
     for cell in CELLS:
-        cfg = registry.load_config(registry.load_cell(cell)["config"])
-        assert reference().param_count() == cfg["model"]["parameters"]
-        assert (reference().forward_flops_per_sample(32)
+        cell, ref = run.prepare(registry.load_cell(cell))
+        cfg = registry.load_config(cell["config"])
+        assert ref.param_count(**cell["spec"]) == cfg["model"]["parameters"]
+        assert (ref.forward_flops_per_sample(**cell["spec"])
                 == cfg["model"]["forward_flops_per_sample"])
+
+
+# What each cell read before its model's sizes came through the
+# reference's SPEC_KEYS: sha256 of the x and label bytes of its
+# check_steps batches for seed 7.
+BATCH_DIGESTS = {
+    "mnv2.3stage.kill":
+        "f2c60e66c28613ce9e3d794aa40a007efac8b0fa79c5019c3b1fb0e61b95d64a",
+    "mnv2.4chip.steady":
+        "d189ca74096699a4bb853917a66292fd1a366cc51732aca8bb0d98f77a6a1d84",
+}
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_builds_the_same_workload_batches_and_profile(name):
+    from benchmarks.chip import run
+    from repro.runtime.devices import WorkloadProfile
+    from repro.runtime.workload import WorkloadSpec
+    cell, ref = run.prepare(registry.load_cell(name))
+    assert cell["spec"] == {"image_hw": 32, "noise": 0.3}
+    assert run.run_config(cell, 7).workload == WorkloadSpec(
+        kind="mobilenet", seed=7, image_hw=32, batch_size=128,
+        num_data_batches=8, noise=0.3)
+    digest = hashlib.sha256()
+    for b in ref.make_batches(7, cell["check_steps"], cell["batch"],
+                              **cell["spec"]):
+        digest.update(b["x"].tobytes())
+        digest.update(b["labels"].tobytes())
+    assert digest.hexdigest() == BATCH_DIGESTS[name]
+    assert ref.forward_flops_per_sample(**cell["spec"]) == 175_952_896
+    got = run.analytic_profile(cell)
+    if "profile" not in cell:
+        assert got is None
+        return
+    want = WorkloadProfile.mobilenetv2(batch=128, image_hw=32)
+    for field in ("fwd_times", "bwd_times", "out_bytes", "weight_bytes"):
+        np.testing.assert_array_equal(getattr(got, field),
+                                      getattr(want, field))
+
+
+def test_mfu_counts_the_steady_cells_flops():
+    from benchmarks.chip import run
+    cell, ref = run.prepare(registry.load_cell("mnv2.4chip.steady"))
+    commits = {b: 0.004 * b for b in range(1, 400)}
+    ctx = types.SimpleNamespace(
+        cell=cell, reference=ref, result=types.SimpleNamespace(
+            commit_times=commits), t_open=0.2, seconds=1.0, chips=4,
+        peaks=peaks.peaks_for("tpu", "TPU v5 lite"))
+    rate = window.samples_per_s(commits, 0.2, 1.0, 128)
+    assert math.isclose(registry.load_reader("mfu").read(ctx),
+                        100.0 * 3 * 175_952_896 * rate / (4 * 197e12))
+    ctx.peaks = None
+    assert registry.load_reader("mfu").read(ctx) is None
+
+
+def test_spec_comes_from_the_cell_else_the_configuration():
+    ref = types.SimpleNamespace(SPEC_KEYS=("width", "noise"))
+    config = {"name": "c", "model": {"kind": "mlp", "width": 64,
+                                     "noise": 0.3}}
+    assert registry.spec_of({"name": "w"}, config, ref) == {
+        "width": 64, "noise": 0.3}
+    assert registry.spec_of({"name": "w", "width": 8}, config, ref) == {
+        "width": 8, "noise": 0.3}
+    with pytest.raises(KeyError, match="depth"):
+        registry.spec_of({"name": "w"}, config, types.SimpleNamespace(
+            SPEC_KEYS=("width", "depth")))
 
 
 @pytest.mark.parametrize("platform,kind", [("tpu", "TPU v9 imaginary"),
